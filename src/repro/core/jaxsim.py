@@ -60,7 +60,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 from jax.ops import segment_sum
 
 from repro.core.vecsim import (
@@ -501,7 +500,7 @@ class JaxSimPool(VecSimPool):
             done_round=np.full(C_pad, -1, np.int64),
             done_col=np.zeros(C_pad, np.int64),
             round_no=np.int64(0))
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _run_kernel(ro, carry)
         out = {k: np.asarray(v) for k, v in out.items()}
         self._writeback(out, cand, C, done)

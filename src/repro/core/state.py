@@ -446,7 +446,7 @@ def featurize_jax_many(clusters, profiles, predict_decodes,
     cache and health columns read host Python objects (PrefixCache
     dicts, gateway health trackers) and are filled host-side exactly
     as the numpy path fills them."""
-    from jax.experimental import enable_x64
+    import jax
     pool = clusters[0].pool
     lanes_cat = np.concatenate([c.lane_ids for c in clusters])
     n = lanes_cat.size
@@ -466,7 +466,7 @@ def featurize_jax_many(clusters, profiles, predict_decodes,
     # values as numpy's empty-axis special case (occ is all-False, so
     # histograms are 0 and T_c is masked by has_res)
     w = max(hw, 1)
-    with enable_x64():
+    with jax.enable_x64(True):
         core = _jax_block()(
             pool.s_state[:, :w][lanes_cat].astype(np.int64),
             pool.s_prompt[:, :w][lanes_cat],

@@ -15,8 +15,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
-from repro.common import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -52,7 +50,7 @@ def make_compressed_dp_grad_fn(loss_fn: Callable, mesh, batch_axes,
         in_specs = (jax.tree.map(lambda _: P(), params),
                     batch_spec_tree)
         out_specs = jax.tree.map(lambda _: P(), params)
-        return compat.shard_map(local_grads, mesh=mesh, in_specs=in_specs,
+        return jax.shard_map(local_grads, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)(
                                  params, batch)
 

@@ -12,8 +12,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-
-from repro.common import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -64,7 +62,7 @@ def pipeline_apply(stage_fn: Callable, mesh, axis: str, stage_params,
         return outs
 
     p_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(p_specs, P()), out_specs=P(),
         check_vma=False)(stage_params, x_micro)

@@ -4,9 +4,9 @@ TPU adaptation (vs a CUDA decode kernel that maps heads to warps): the
 GQA q-head GROUP (g rows) x head_dim tile is the MXU's M x K operand and
 the KV sequence is swept in (block_k x head_dim) VMEM tiles with an
 online-softmax scratch carry -- the sweep is the memory-bound part and is
-what the roofline's HBM term measures.  Valid-length masking comes from a
-scalar-memory (SMEM) per-batch length, so padded cache tail blocks add
-no numerical effect.
+what the roofline's HBM term measures.  Valid-length masking comes from the
+per-batch lengths, held whole in scalar memory (SMEM) and indexed by the
+batch grid index, so padded cache tail blocks add no numerical effect.
 """
 from __future__ import annotations
 
@@ -17,9 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in jax 0.5
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
 
 NEG_INF = -1e30
 
@@ -27,6 +24,7 @@ NEG_INF = -1e30
 def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                 *, block_k: int, sm_scale: float, n_kv: int,
                 k_scale_ref=None, v_scale_ref=None):
+    bi = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -45,7 +43,7 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                             preferred_element_type=jnp.float32) * sm_scale
     kv_pos = ik * block_k + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1)
-    s = jnp.where(kv_pos < len_ref[0], s, NEG_INF)
+    s = jnp.where(kv_pos < len_ref[bi], s, NEG_INF)
     m_prev = m_ref[...]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
@@ -86,8 +84,8 @@ def decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(_dec_kernel, block_k=block_k,
                                sm_scale=1.0 / (hd ** 0.5), n_kv=n_kv)
     in_specs = [
-        pl.BlockSpec((1,), lambda bi, ki, ii: (bi,),
-                     memory_space=pltpu.SMEM),
+        # whole [B] array: a (1,) SMEM block is refused by the TPU lowering
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, g, hd), lambda bi, ki, ii: (bi, ki, 0, 0)),
         pl.BlockSpec((1, 1, block_k, hd),
                      lambda bi, ki, ii: (bi, ki, ii, 0)),
@@ -121,7 +119,7 @@ def decode_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -17,9 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in jax 0.5
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
 
 NEG_INF = -1e30
 
@@ -105,7 +102,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

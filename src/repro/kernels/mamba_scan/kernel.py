@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in jax 0.5
-_CompilerParams = getattr(pltpu, 'CompilerParams',
-                          getattr(pltpu, 'TPUCompilerParams', None))
-
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref,
                  hout_ref, h_ref, *, chunk: int, n_chunks: int):
@@ -89,7 +85,7 @@ def mamba_scan_kernel(xc, dt, b, c, a_log, d, h0=None, *, chunk: int = 256,
             jax.ShapeDtypeStruct((bsz, di, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_c, ds), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xc, dt, b, c, a_log, d)

@@ -26,7 +26,6 @@ from repro.configs import ASSIGNED, get_config
 from repro.distributed import context as dist_ctx
 from repro.distributed import sharding
 from repro.launch import steps as steps_lib
-from repro.launch import mesh as mesh_lib
 from repro.launch.mesh import batch_axes, make_production_mesh
 
 REPLICATED_OK = ("pos",)
@@ -76,7 +75,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, multi_pod: bool):
     p_shard = sharding.params_shardings(cfg, p_specs, mesh, mode)
     batch = steps_lib.input_specs(cfg, shape)
     b_shard = sharding.input_shardings(cfg, mesh, batch)
-    with dist_ctx.use(ctx), mesh_lib.set_mesh(mesh):
+    with dist_ctx.use(ctx), jax.set_mesh(mesh):
         if shape.kind == "train":
             optname = _optimizer_for(cfg)
             step = steps_lib.build_train_step(cfg, optname)

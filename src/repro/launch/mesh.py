@@ -8,13 +8,8 @@ from __future__ import annotations
 import jax
 
 
-def _axis_kwargs(n_axes: int) -> dict:
-    """axis_types only exists from jax 0.5; Auto is the default there, so
-    on older jax we simply omit the argument."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,21 +23,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Generic helper for tests / small host-device meshes."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_kwargs(len(axes)))
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient: jax.set_mesh on jax>=0.5,
-    the Mesh context manager (legacy ambient mesh) before that."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+                         axis_types=_auto(len(axes)))
 
 
 def mesh_axes(mesh) -> tuple:

@@ -1,5 +1,5 @@
-"""Serving launcher: intelligent-router cluster over real (reduced) JAX
-instances or the calibrated simulator.
+"""Serving launcher: intelligent-router cluster over real JAX engine
+replicas or the calibrated simulator.
 
   # online gateway: open-loop multi-tenant stream, pluggable policy,
   # learned length predictor in the loop, rolling SLO metrics
@@ -11,9 +11,12 @@ instances or the calibrated simulator.
   PYTHONPATH=src python -m repro.launch.serve --mode gateway \
       --trace trace.json --metrics-out metrics.json
 
-  # gateway over real tiny engines on CPU
+  # gateway over real engines: qwen3-0.6b at published widths, one
+  # replica per accelerator (TPU); --reduced runs the tiny preset on CPU
   PYTHONPATH=src python -m repro.launch.serve --mode gateway \
-      --backend engine --policy mixing --requests 12
+      --backend engine --instances 1 --requests 12
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --mode gateway --backend engine --reduced --requests 12
 
   # chaos drill: seeded crash/straggler schedule with gateway failover
   # (bounded-retry re-admission, circuit breaker, hedged re-dispatch)
@@ -42,14 +45,17 @@ instances or the calibrated simulator.
   PYTHONPATH=src python -m repro.launch.serve --mode sim --requests 400
 
   # real tiny engines, impact-heuristic routing (legacy path)
-  PYTHONPATH=src python -m repro.launch.serve --mode engine --requests 12
+  PYTHONPATH=src python -m repro.launch.serve --mode engine --reduced \
+      --requests 12
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import os
+import pathlib
 import sys
+from concurrent.futures import ThreadPoolExecutor
 
 # calibration times jitted kernels: single-threaded XLA keeps the sweep
 # linear (multi-threaded CPU XLA switches parallelization strategy with
@@ -70,7 +76,7 @@ from repro.core import impact, rl_router as rl
 from repro.core import workload as wl
 from repro.core.cluster_manager import ManagedCluster, ManagedClusterConfig
 from repro.core.predictor import quick_bucket_predictor
-from repro.core.profiles import V100_LLAMA2_7B
+from repro.core.profiles import V100_LLAMA2_7B, tpu_v5e_profile
 from repro.core.workload import generate, to_requests
 from repro.models import params as params_lib
 from repro.serving.engine import LLMInstance
@@ -81,6 +87,97 @@ from repro.serving.policies import (RLPolicy, make_gateway_policy,
                                     restore_rl_policy)
 from repro.serving.request import Request, summarize
 from repro.serving.scheduler import get_scheduler
+
+
+# the checkout this module lives in (src/repro/launch/serve.py)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+# engine-path request draws, [lo, hi) token counts
+ENGINE_PROMPTS = (10, 80)
+ENGINE_DECODES = (5, 60)
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compile cache where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads that itself), else at ``<checkout>/.jax_cache``.
+    Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_engine_fleet(arch: str, n_instances: int, *,
+                       reduced: bool = False, n_slots: int = 8,
+                       cache_len: int = 2048, seed: int = 0,
+                       profile=None, scheduler: str = "fcfs",
+                       prefix_cache_tokens: int = 0,
+                       prefix_block: int = 32):
+    """``n_instances`` engine replicas of ``arch`` sharing params drawn
+    from ``seed``.
+
+    By default the model runs at its published config (bf16) with the
+    Pallas kernels; ``reduced`` gives the tiny float32 jnp preset CPU
+    callers use.  Replica i lives on device i of ``jax.devices()``,
+    round robin.  ``profile`` drives the virtual clock and the router's
+    scores; it defaults to the analytic one-chip v5e profile of the
+    published model, and is clamped to the KV the replicas hold."""
+    published = get_config(arch)
+    cfg = (published.reduced() if reduced
+           else dataclasses.replace(published, use_pallas=True))
+    params = params_lib.init_params(jax.random.PRNGKey(seed), cfg)
+    if profile is None:
+        profile = tpu_v5e_profile(published.count_params(), tp=1,
+                                  name=f"v5e-{arch}")
+    profile = dataclasses.replace(
+        profile, capacity_tokens=min(profile.capacity_tokens,
+                                     n_slots * cache_len))
+    devices = jax.devices()
+    return [LLMInstance(cfg, params, profile, get_scheduler(scheduler),
+                        n_slots=n_slots, cache_len=cache_len,
+                        instance_id=i,
+                        prefix_cache_tokens=prefix_cache_tokens,
+                        prefix_block=prefix_block,
+                        device=devices[i % len(devices)])
+            for i in range(n_instances)]
+
+
+def warm_fleet(engines, prompt_lengths) -> None:
+    """Run each replica's prefill at every prompt length, its slot
+    insert and its decode step once, compiling in parallel threads, so
+    nothing compiles while serving.  Slots stay empty."""
+    lengths = sorted(set(int(n) for n in prompt_lengths))
+
+    def prefill(job):
+        e, n = job
+        toks = jax.device_put(np.zeros((1, n), np.int32), e.device)
+        return jax.block_until_ready(e.prefill_fn(e.params, toks))
+
+    def insert_and_decode(e):
+        _, small = prefill((e, lengths[0]))
+        e.cache = e.insert_fn(e.cache, small, 0)
+        toks = jax.device_put(e.next_tokens, e.device)
+        _, e.cache = e.decode_fn(e.params, e.cache, toks)
+        jax.block_until_ready(e.cache)
+
+    with ThreadPoolExecutor(os.cpu_count()) as ex:
+        list(ex.map(prefill, [(e, n) for e in engines for n in lengths]))
+        list(ex.map(insert_and_decode, engines))
+
+
+def draw_engine_requests(n: int, vocab: int, seed: int) -> list:
+    """The engine path's request stream: lengths from ENGINE_PROMPTS /
+    ENGINE_DECODES and prompt ids from ``seed``, 50 ms apart."""
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for i in range(n):
+        p = int(rng.integers(*ENGINE_PROMPTS))
+        reqs.append(Request(prompt_tokens=p,
+                            decode_tokens=int(rng.integers(*ENGINE_DECODES)),
+                            arrival=0.05 * i, tenant="engine",
+                            tokens=rng.integers(0, vocab, p).tolist()))
+    return reqs
 
 
 def _router_cfg(args) -> rl.RouterConfig:
@@ -158,18 +255,24 @@ def serve_sim(args):
           f"preemptions={stats['preemptions']}")
 
 
-def _tiny_engines(args, capacity: int = 400):
-    cfg = get_config(args.arch).reduced()
-    base = _base_profile(args)
-    prof = dataclasses.replace(
-        base, capacity_tokens=min(base.capacity_tokens, capacity))
-    params = params_lib.init_params(jax.random.PRNGKey(0), cfg)
-    return [LLMInstance(cfg, params, prof,
-                        get_scheduler(args.scheduler), n_slots=4,
-                        cache_len=128, instance_id=i,
-                        prefix_cache_tokens=args.prefix_cache,
-                        prefix_block=args.prefix_block)
-            for i in range(args.instances)]
+def _engines(args) -> list:
+    """The CLI's engine fleet: published widths on the accelerator, or
+    with --reduced the tiny CPU preset (4 slots, a cache that fits the
+    longest draw, a 400-token KV budget on the base profile)."""
+    if args.reduced:
+        base = _base_profile(args)
+        sizes = dict(n_slots=4,
+                     cache_len=ENGINE_PROMPTS[1] + ENGINE_DECODES[1],
+                     profile=dataclasses.replace(
+                         base, capacity_tokens=min(base.capacity_tokens,
+                                                   400)))
+    else:
+        sizes = dict(profile=(cal.load_profile(args.profile_json)
+                              if args.profile_json else None))
+    return build_engine_fleet(
+        args.arch, args.instances, reduced=args.reduced, seed=args.seed,
+        scheduler=args.scheduler, prefix_cache_tokens=args.prefix_cache,
+        prefix_block=args.prefix_block, **sizes)
 
 
 def _chaos_schedule(args):
@@ -228,31 +331,22 @@ def serve_gateway(args):
             raise SystemExit("--online needs a simulator backend "
                              "(py/vec): the engine adapter fires no "
                              "decode events for the backlog reward)")
-        # tiny real engines: short random prompts, oracle-free routing
-        # via the mixing heuristic (no content for the predictor)
-        engines = _tiny_engines(args)
+        if args.policy == "rl" and not args.checkpoint:
+            raise SystemExit("--backend engine --policy rl needs "
+                             "--checkpoint (no simulator to train on)")
+        # real engines: seeded random prompts, oracle-free routing (no
+        # content for the predictor)
+        engines = _engines(args)
         cluster = EngineClusterAdapter(engines)
-        policy_name = args.policy
-        if policy_name == "rl":
-            if args.checkpoint:
-                policy = restore_rl_policy(cfg, args.checkpoint,
-                                           m=args.instances)
-            else:
-                print("WARNING: --backend engine --policy rl needs "
-                      "--checkpoint (no simulator to train on); "
-                      "falling back to the mixing policy")
-                policy_name = "mixing"
-                policy = make_gateway_policy(policy_name, cfg)
+        if args.policy == "rl":
+            policy = restore_rl_policy(cfg, args.checkpoint,
+                                       m=args.instances)
         else:
-            policy = make_gateway_policy(policy_name, cfg)
+            policy = make_gateway_policy(args.policy, cfg)
         gw = Gateway(gcfg, None, policy, cluster=cluster,
                      trace=recorder)
-        rng = np.random.default_rng(0)
-        reqs = [Request(prompt_tokens=int(rng.integers(10, 80)),
-                        decode_tokens=int(rng.integers(5, 60)),
-                        arrival=float(i) * 0.05, tenant="engine")
-                for i in range(args.requests)]
-        stats = gw.run(reqs)
+        stats = gw.run(draw_engine_requests(
+            args.requests, engines[0].cfg.vocab_size, args.seed))
     else:
         base = _base_profile(args)
         profiles = (base,) * args.instances
@@ -345,12 +439,10 @@ def serve_gateway(args):
 
 
 def serve_engine(args):
-    insts = _tiny_engines(args)
+    insts = _engines(args)
     prof = insts[0].profile
-    rng = np.random.default_rng(0)
-    reqs = [Request(prompt_tokens=int(rng.integers(10, 80)),
-                    decode_tokens=int(rng.integers(5, 60)))
-            for _ in range(args.requests)]
+    reqs = draw_engine_requests(args.requests, insts[0].cfg.vocab_size,
+                                args.seed)
     for r in reqs:   # impact-heuristic routing (Eq. 1-2)
         scores = impact.mixing_per_instance(
             prof, r.prompt_tokens, r.decode_tokens,
@@ -375,9 +467,15 @@ def main():
                     default="sim",
                     help="gateway cluster backend: any name from the "
                     "core.backends registry ('py'/'vec'/'jax' pick the "
-                    "simulator stepper, 'engine' runs tiny real "
-                    "engines); 'sim' is the legacy alias for the "
+                    "simulator stepper, 'engine' runs real engine "
+                    "replicas); 'sim' is the legacy alias for the "
                     "default simulator stepper")
+    ap.add_argument("--reduced", action="store_true",
+                    help="engine replicas at the tiny CPU preset "
+                    "(ModelConfig.reduced) instead of the published "
+                    "widths")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="engine params and prompt ids")
     ap.add_argument("--policy", default="mixing",
                     choices=("rl", "mixing", "mixing+cache", "jsq",
                              "rr", "sticky"),
@@ -479,7 +577,7 @@ def main():
     ap.add_argument("--min-r2", type=float, default=0.0,
                     help="with --calibrate: exit non-zero unless both "
                     "fits reach this R^2 (CI gate)")
-    ap.add_argument("--arch", default="llama-2-7b")
+    ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--instances", type=int, default=4)
     ap.add_argument("--requests", type=int, default=400)
     ap.add_argument("--rate", type=float, default=20.0)
@@ -487,6 +585,7 @@ def main():
     ap.add_argument("--chunked-prefill", type=int, default=0)
     ap.add_argument("--train-episodes", type=int, default=8)
     args = ap.parse_args()
+    use_compile_cache()
     if args.calibrate:
         sys.exit(run_calibrate(args))
     if args.mode == "sim":

@@ -20,12 +20,14 @@ def gqa_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 unroll: bool = False) -> jax.Array:
     """Memory-blocked attention (jnp flash-style): scans q blocks so the
     score matrix never materializes beyond [B, KV, G, block_q, Skv].
-    Required for the 32k/500k cells where full S^2 scores would OOM."""
+    Required for the 32k/500k cells where full S^2 scores would OOM.
+    Queries are zero-padded up to a block multiple and sliced back."""
+    sq_in = q.shape[1]
+    block_q = min(block_q, sq_in)
+    q = jnp.pad(q, ((0, 0), (0, -sq_in % block_q), (0, 0), (0, 0)))
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     g = h // kvh
-    block_q = min(block_q, sq)
-    assert sq % block_q == 0
     n_blocks = sq // block_q
     qg = q.reshape(b, n_blocks, block_q, kvh, g, hd).transpose(
         1, 0, 2, 3, 4, 5)                       # [n, B, bq, KV, G, hd]
@@ -59,7 +61,7 @@ def gqa_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     _, outs = jax.lax.scan(body, None, (qg, jnp.arange(n_blocks)),
                            unroll=True if unroll else 1)
     out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, h, hd)
-    return out.astype(q.dtype)
+    return out[:, :sq_in].astype(q.dtype)
 
 
 def quantize_kv(x: jax.Array):

@@ -23,8 +23,6 @@ import functools
 from typing import Dict, Tuple
 
 import jax
-
-from repro.common import compat
 import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
@@ -221,7 +219,7 @@ def moe_ep(p: Dict, cfg: ModelConfig, x_flat: jax.Array):
     fn = functools.partial(_ep_local, cfg=cfg, n_shards=n_shards,
                            ep_axes=ep_axes, tp_axis=tp_axis,
                            aux_axes=batch_axes)
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, pspec(None, None), w3, w3, w3d),
         out_specs=(x_spec, pspec()),

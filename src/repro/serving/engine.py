@@ -10,9 +10,11 @@ One ``LLMInstance`` = one model replica (on the production mesh: one
     as in vLLM) when decode growth overflows the budget,
   * per-request lifecycle metrics (TTFT / TBT / E2E) on the virtual clock.
 
-The engine is exercised with reduced configs on CPU (examples, tests); the
-discrete-event simulator in ``repro.core.simulator`` reproduces the paper's
-timing experiments at scale using the same Request/scheduler abstractions.
+On a TPU the replicas run at published widths, one per chip
+(``device=``), with the Pallas kernels; on CPU (examples, tests) they run
+reduced configs.  The discrete-event simulator in ``repro.core.simulator``
+reproduces the paper's timing experiments at scale using the same
+Request/scheduler abstractions.
 """
 from __future__ import annotations
 
@@ -62,15 +64,23 @@ class LLMInstance:
     def __init__(self, cfg: ModelConfig, params, profile: HardwareProfile,
                  scheduler: InstanceScheduler, n_slots: int = 8,
                  cache_len: int = 256, instance_id: int = 0,
-                 prefix_cache_tokens: int = 0, prefix_block: int = 32):
+                 prefix_cache_tokens: int = 0, prefix_block: int = 32,
+                 device: Optional[jax.Device] = None):
         assert cfg.input_mode == "tokens", "engine path uses token inputs"
+        # params and KV cache live on ``device`` (the default device when
+        # None); the jitted steps follow their committed inputs there
+        self.device = device
+        if device is not None:
+            params = jax.device_put(params, device)
         self.cfg, self.params, self.profile = cfg, params, profile
         self.scheduler = scheduler
         self.n_slots, self.cache_len = n_slots, cache_len
         self.instance_id = instance_id
         self.prefill_fn, self.insert_fn, self.decode_fn = _build_fns(
             cfg, cache_len)
-        self.cache = model_lib.init_cache(cfg, n_slots, cache_len)
+        with jax.default_device(device):
+            self.cache = jax.device_put(
+                model_lib.init_cache(cfg, n_slots, cache_len), device)
         self.slots: List[Optional[Request]] = [None] * n_slots
         self.next_tokens = np.zeros((n_slots,), np.int32)
         self.queue: deque = deque()
@@ -124,6 +134,13 @@ class LLMInstance:
 
     # -- request intake ------------------------------------------------------
     def submit(self, req: Request):
+        # the KV cache holds the prompt plus every decoded token but the
+        # last; past cache_len the decode step would write nothing
+        if req.prompt_tokens + req.decode_tokens > self.cache_len:
+            raise ValueError(
+                f"request {req.rid}: prompt {req.prompt_tokens} + decode "
+                f"{req.decode_tokens} tokens exceed cache_len "
+                f"{self.cache_len}")
         req.phase = Phase.INSTANCE_QUEUE
         req.instance = self.instance_id
         req.routed_at = self.clock
@@ -185,7 +202,8 @@ class LLMInstance:
             rng = np.random.default_rng(req.rid)
             toks = rng.integers(0, self.cfg.vocab_size,
                                 size=(req.prompt_tokens,))
-        toks = jnp.asarray(np.asarray(toks, np.int32)[None, :])
+        toks = jax.device_put(np.asarray(toks, np.int32)[None, :],
+                              self.device)
         logits, small = self.prefill_fn(self.params, toks)
         self.cache = self.insert_fn(self.cache, small, slot)
         self.slots[slot] = req
@@ -199,7 +217,7 @@ class LLMInstance:
         done: List[Request] = []
         if not active:
             return done
-        toks = jnp.asarray(self.next_tokens)
+        toks = jax.device_put(self.next_tokens, self.device)
         logits, self.cache = self.decode_fn(self.params, self.cache, toks)
         nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         tr = self.trace
@@ -212,6 +230,7 @@ class LLMInstance:
                     tr.emit(self.clock, _trace.EV_FIRST_TOKEN, r.rid,
                             self.trace_instance, r.tenant)
             r.token_times.append(self.clock)
+            r.output_tokens.append(int(self.next_tokens[i]))
             self.next_tokens[i] = nxt[i]
             if r.decoded >= r.decode_tokens:
                 r.phase = Phase.DONE

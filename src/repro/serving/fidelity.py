@@ -136,8 +136,9 @@ def make_stream(fcfg: FidelityConfig) -> List[tuple]:
                 for p, d, t in zip(lengths, decodes, arrivals)]
     B = fcfg.prefix_block
     # context ladder bounded so the engine compiles few prefill shapes
-    # and every turn fits the engine-sized KV budget
-    max_blocks = min(int(fcfg.capacity_tokens * 0.9) // B, 10)
+    # and every turn fits the engine-sized KV budget and cache
+    max_blocks = min(int(fcfg.capacity_tokens * 0.9) // B,
+                     fcfg.cache_len // B, 10)
     n_sessions = max(fcfg.n_requests // 3, 1)
     starts = np.cumsum(rng.exponential(3.0 / fcfg.rate,
                                        size=n_sessions))

@@ -52,6 +52,7 @@ class Request:
     prefilled: int = 0                       # prompt tokens processed
     admitted_idx: int = -1                   # admission order (eviction)
     token_times: List[float] = field(default_factory=list)
+    output_tokens: List[int] = field(default_factory=list)  # engine ids
     preemptions: int = 0
     cached_prefix: int = 0                   # prefill tokens served from cache
     retries: int = 0                         # crash-orphan re-admissions
@@ -81,6 +82,7 @@ class Request:
     def reset_progress(self):
         """Preemption: work is lost; request restarts its prefill."""
         self.decoded = 0
+        self.output_tokens = []
         self.prefilled = 0
         self.cached_prefix = 0
         self.phase = Phase.PREEMPTED

@@ -6,7 +6,6 @@ import numpy as np
 def test_pipeline_parallel_matches_sequential(subproc):
     out = subproc("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch import mesh as mesh_lib
         from repro.launch.mesh import make_mesh
         from repro.distributed import pipeline as pp
         mesh = make_mesh((4,), ('pipe',))
@@ -27,7 +26,6 @@ def test_compressed_allreduce_accuracy(subproc):
     out = subproc("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.launch import mesh as mesh_lib
         from repro.launch.mesh import make_mesh
         from repro.distributed import compression as comp
         mesh = make_mesh((8,), ('data',))
@@ -40,7 +38,7 @@ def test_compressed_allreduce_accuracy(subproc):
         gf = comp.make_compressed_dp_grad_fn(
             loss, mesh, ('data',),
             {'x': P('data', None), 'y': P('data', None)})
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             approx = jax.jit(gf)(params, batch)
         rel = float(jnp.abs(approx['w'] - exact['w']).max()
                     / jnp.abs(exact['w']).max())
@@ -55,7 +53,6 @@ def test_ep_moe_matches_ragged(subproc):
         from repro.configs import REGISTRY
         from repro.models import params as P, moe as MoE
         from repro.distributed import context as dist_ctx
-        from repro.launch import mesh as mesh_lib
         from repro.launch.mesh import make_mesh
         cfg = REGISTRY['deepseek-moe-16b'].reduced()
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
@@ -70,7 +67,7 @@ def test_ep_moe_matches_ragged(subproc):
         ctx = dist_ctx.ParallelContext(
             mesh=mesh, batch_axes=('data',), model_axis='model',
             ep_axes=('data',))
-        with dist_ctx.use(ctx), mesh_lib.set_mesh(mesh):
+        with dist_ctx.use(ctx), jax.set_mesh(mesh):
             yep, auxep = jax.jit(
                 lambda p, x: MoE.moe_ep(p, cfg_ep, x))(moe_p, x)
         print('ERR', float(jnp.abs(yep - yr).max()))

@@ -16,6 +16,9 @@ from repro.kernels.mamba_scan import ops as ms
     (2, 256, 8, 2, 64, 128, 64),
     (1, 512, 4, 1, 128, 128, 128),     # MQA
     (2, 128, 6, 2, 64, 128, 128),      # blocks > S get clamped
+    (1, 37, 4, 2, 32, 128, 128),       # one block shorter than 128
+    (1, 200, 4, 2, 32, 128, 128),      # padded up to a block multiple
+    (2, 300, 4, 4, 32, 128, 64),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(shape, dtype):

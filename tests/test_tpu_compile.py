@@ -1,0 +1,69 @@
+"""Compile the Pallas kernels of the serving path for a described TPU v5e.
+
+Nothing runs: the TPU compiler, which is installed with jax, compiles for
+a chip that is described and not attached.  A kernel that interpret mode
+accepts can still be refused here (block shapes not aligned to the
+tiling, too much fast memory), so these tests guard what the CPU kernel
+tests cannot.  The topology is described inside a fixture, never at
+import, so every test worker collects the same tests.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.decode_attention.kernel import decode_attention_kernel
+from repro.kernels.flash_attention import ops as fa
+
+QWEN3 = get_config("qwen3-0.6b")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _hlo(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_decode_kernel_compiles_for_v5e(one_chip):
+    b, s, h, kv, hd = 8, 2048, QWEN3.n_heads, QWEN3.n_kv_heads, 128
+    bf16 = jnp.bfloat16
+    hlo = _hlo(functools.partial(decode_attention_kernel, interpret=False),
+               one_chip, ((b, 1, h, hd), bf16), ((b, s, kv, hd), bf16),
+               ((b, s, kv, hd), bf16), ((b,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("sq", [37, 128, 200, 512])
+def test_flash_op_compiles_for_v5e(one_chip, sq):
+    h, kv, hd = QWEN3.n_heads, QWEN3.n_kv_heads, QWEN3.head_dim
+    bf16 = jnp.bfloat16
+    hlo = _hlo(functools.partial(fa.flash_attention, interpret=False),
+               one_chip, ((1, sq, h, hd), bf16), ((1, sq, kv, hd), bf16),
+               ((1, sq, kv, hd), bf16))
+    assert "tpu_custom_call" in hlo
