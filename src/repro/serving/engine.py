@@ -19,6 +19,7 @@ Request/scheduler abstractions.
 from __future__ import annotations
 
 import functools
+import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -37,9 +38,11 @@ from repro.serving.scheduler import InstanceScheduler
 
 @functools.lru_cache(maxsize=32)
 def _build_fns(cfg: ModelConfig, cache_len: int):
-    prefill = jax.jit(
-        lambda params, tokens: model_lib.prefill(params, cfg, tokens=tokens,
-                                                 cache_len=cache_len))
+    """The jitted prefill, slot insert and decode step, named so that
+    XLA's modules and compile logs say which is which."""
+    def prefill(params, tokens):
+        return model_lib.prefill(params, cfg, tokens=tokens,
+                                 cache_len=cache_len)
 
     def insert(cache, new, slot):
         def one(path, full, small):
@@ -52,12 +55,11 @@ def _build_fns(cfg: ModelConfig, cache_len: int):
         out = jax.tree_util.tree_map_with_path(one, cache, new)
         return out
 
-    insert_j = jax.jit(insert, donate_argnums=(0,))
-    decode = jax.jit(
-        lambda params, cache, toks: model_lib.decode_step(params, cfg, cache,
-                                                          tokens=toks),
-        donate_argnums=(1,))
-    return prefill, insert_j, decode
+    def decode_step(params, cache, toks):
+        return model_lib.decode_step(params, cfg, cache, tokens=toks)
+
+    return (jax.jit(prefill), jax.jit(insert, donate_argnums=(0,)),
+            jax.jit(decode_step, donate_argnums=(1,)))
 
 
 class LLMInstance:
@@ -153,61 +155,67 @@ class LLMInstance:
         iteration; advances the virtual clock."""
         if self.failed:
             return []
-        prefill_tokens = 0
-        # admission (scheduler's choice among queued)
-        free_slot = next((i for i, s in enumerate(self.slots) if s is None),
-                         None)
-        if free_slot is not None and self.queue:
-            budget = self.profile.capacity_tokens - self.resident_tokens()
-            pick = self.scheduler.pick(list(self.queue), budget,
-                                       self.profile)
-            if pick is not None:
-                req = self.queue[pick]
-                del self.queue[pick]
-                self._admit(req, free_slot)
-                cached = 0
-                if self.prefix_cache is not None and req.prefix_hashes:
-                    cached = self.prefix_cache.admit(req.prompt_tokens,
-                                                     req.prefix_hashes)
-                    req.cached_prefix = cached
-                # cached prefix costs no prefill compute on the virtual
-                # clock; it re-enters iteration_time as resident
-                # context below (same split as SimInstance)
-                prefill_tokens += req.prompt_tokens - cached
-                if self.trace.enabled:
-                    self.trace.emit(self.clock, _trace.EV_INST_ADMIT,
-                                    req.rid, self.trace_instance,
-                                    req.tenant, {"cached": int(cached)})
-                    self.trace.emit(self.clock, _trace.EV_PREFILL_DONE,
-                                    req.rid, self.trace_instance,
-                                    req.tenant)
-        # charge the iteration BEFORE running the decode pass: the
-        # resident-context term is the pre-decode sum and the tokens
-        # produced this iteration are stamped at its END, exactly like
-        # SimInstance._iteration (TTFT/E2E anchors compare
-        # like-for-like in the fidelity harness)
-        resident_other = max(self.resident_tokens() - prefill_tokens, 0)
-        self.clock += self.profile.iteration_time(
-            prefill_tokens, resident_other) * self.speed_factor
-        completions = self._decode_iteration()
-        # capacity enforcement: evict newest-admitted if over budget
-        while (self.resident_tokens() > self.profile.capacity_tokens
-               and len(self.resident) > 1):
-            self._preempt_newest()
-        return completions
+        with _trace.span("engine.step", replica=self.trace_instance,
+                         rows=len(self.slots) - self.slots.count(None)):
+            prefill_tokens = 0
+            # admission (scheduler's choice among queued)
+            free_slot = next(
+                (i for i, s in enumerate(self.slots) if s is None), None)
+            if free_slot is not None and self.queue:
+                budget = self.profile.capacity_tokens - self.resident_tokens()
+                pick = self.scheduler.pick(list(self.queue), budget,
+                                           self.profile)
+                if pick is not None:
+                    req = self.queue[pick]
+                    del self.queue[pick]
+                    self._admit(req, free_slot)
+                    cached = 0
+                    if self.prefix_cache is not None and req.prefix_hashes:
+                        cached = self.prefix_cache.admit(req.prompt_tokens,
+                                                         req.prefix_hashes)
+                        req.cached_prefix = cached
+                    # cached prefix costs no prefill compute on the virtual
+                    # clock; it re-enters iteration_time as resident
+                    # context below (same split as SimInstance)
+                    prefill_tokens += req.prompt_tokens - cached
+                    if self.trace.enabled:
+                        self.trace.emit(self.clock, _trace.EV_INST_ADMIT,
+                                        req.rid, self.trace_instance,
+                                        req.tenant, {"cached": int(cached)})
+                        self.trace.emit(self.clock, _trace.EV_PREFILL_DONE,
+                                        req.rid, self.trace_instance,
+                                        req.tenant)
+            # charge the iteration BEFORE running the decode pass: the
+            # resident-context term is the pre-decode sum and the tokens
+            # produced this iteration are stamped at its END, exactly like
+            # SimInstance._iteration (TTFT/E2E anchors compare
+            # like-for-like in the fidelity harness)
+            resident_other = max(self.resident_tokens() - prefill_tokens, 0)
+            self.clock += self.profile.iteration_time(
+                prefill_tokens, resident_other) * self.speed_factor
+            completions = self._decode_iteration()
+            # capacity enforcement: evict newest-admitted if over budget
+            while (self.resident_tokens() > self.profile.capacity_tokens
+                   and len(self.resident) > 1):
+                self._preempt_newest()
+            return completions
 
     def _admit(self, req: Request, slot: int):
+        req.wall_started = time.perf_counter()
         toks = req.tokens
         if toks is None:
             rng = np.random.default_rng(req.rid)
             toks = rng.integers(0, self.cfg.vocab_size,
                                 size=(req.prompt_tokens,))
-        toks = jax.device_put(np.asarray(toks, np.int32)[None, :],
-                              self.device)
-        logits, small = self.prefill_fn(self.params, toks)
-        self.cache = self.insert_fn(self.cache, small, slot)
-        self.slots[slot] = req
-        self.next_tokens[slot] = int(jnp.argmax(logits[0]))
+        with _trace.span("engine.prefill", rid=req.rid,
+                         prompt=req.prompt_tokens):
+            toks = jax.device_put(np.asarray(toks, np.int32)[None, :],
+                                  self.device)
+            logits, small = self.prefill_fn(self.params, toks)
+            self.cache = self.insert_fn(self.cache, small, slot)
+            self.slots[slot] = req
+            with _trace.span("engine.fetch"):
+                self.next_tokens[slot] = int(jnp.argmax(logits[0]))
         req.phase = Phase.DECODE
         req.prefilled = req.prompt_tokens
         req.prefill_done = self.clock
@@ -217,32 +225,34 @@ class LLMInstance:
         done: List[Request] = []
         if not active:
             return done
-        toks = jax.device_put(self.next_tokens, self.device)
-        logits, self.cache = self.decode_fn(self.params, self.cache, toks)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        tr = self.trace
-        for i in active:
-            r = self.slots[i]
-            r.decoded += 1
-            if r.first_token is None:
-                r.first_token = self.clock
-                if tr.enabled:
-                    tr.emit(self.clock, _trace.EV_FIRST_TOKEN, r.rid,
-                            self.trace_instance, r.tenant)
-            r.token_times.append(self.clock)
-            r.output_tokens.append(int(self.next_tokens[i]))
-            self.next_tokens[i] = nxt[i]
-            if r.decoded >= r.decode_tokens:
-                r.phase = Phase.DONE
-                r.finished = self.clock
-                if tr.enabled:
-                    tr.emit(self.clock, _trace.EV_COMPLETE, r.rid,
-                            self.trace_instance, r.tenant)
-                if self.prefix_cache is not None and r.full_hashes:
-                    self.prefix_cache.insert(r.full_hashes)
-                self.completed.append(r)
-                self.slots[i] = None
-                done.append(r)
+        with _trace.span("engine.decode"):
+            toks = jax.device_put(self.next_tokens, self.device)
+            logits, self.cache = self.decode_fn(self.params, self.cache, toks)
+            with _trace.span("engine.fetch"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            tr = self.trace
+            for i in active:
+                r = self.slots[i]
+                r.decoded += 1
+                if r.first_token is None:
+                    r.first_token = self.clock
+                    if tr.enabled:
+                        tr.emit(self.clock, _trace.EV_FIRST_TOKEN, r.rid,
+                                self.trace_instance, r.tenant)
+                r.token_times.append(self.clock)
+                r.output_tokens.append(int(self.next_tokens[i]))
+                self.next_tokens[i] = nxt[i]
+                if r.decoded >= r.decode_tokens:
+                    r.phase = Phase.DONE
+                    r.finished = self.clock
+                    if tr.enabled:
+                        tr.emit(self.clock, _trace.EV_COMPLETE, r.rid,
+                                self.trace_instance, r.tenant)
+                    if self.prefix_cache is not None and r.full_hashes:
+                        self.prefix_cache.insert(r.full_hashes)
+                    self.completed.append(r)
+                    self.slots[i] = None
+                    done.append(r)
         return done
 
     def _preempt_newest(self):

@@ -36,6 +36,7 @@ real ``serving.engine.LLMInstance`` replicas (``EngineClusterAdapter``).
 from __future__ import annotations
 
 import heapq
+import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -216,7 +217,6 @@ class EngineClusterAdapter:
         self.central: deque = deque()
         self.t = 0.0
         self.completed: List[Request] = []
-        self.queue_len_trace: List[int] = []
 
     @property
     def m(self) -> int:
@@ -244,18 +244,18 @@ class EngineClusterAdapter:
     def advance(self) -> List[Request]:
         self.t += self.dt
         done: List[Request] = []
-        for e in self.engines:
-            if e.failed:
-                e.clock = self.t
-                continue
-            while e.clock < self.t:
-                if not e.queue and not any(
-                        s is not None for s in e.slots):
+        with tr_lib.span("cluster.advance"):
+            for e in self.engines:
+                if e.failed:
                     e.clock = self.t
-                    break
-                done.extend(e.step())
+                    continue
+                while e.clock < self.t:
+                    if not e.queue and not any(
+                            s is not None for s in e.slots):
+                        e.clock = self.t
+                        break
+                    done.extend(e.step())
         self.completed.extend(done)
-        self.queue_len_trace.append(len(self.central))
         return done
 
     # -- fault injection (Cluster parity) ------------------------------
@@ -454,6 +454,7 @@ class Gateway:
                     tr.emit(self.cluster.t, tr_lib.EV_DEFER, req.rid,
                             -1, req.tenant)
             return
+        req.wall_queued = time.perf_counter()
         self.cluster.enqueue(req)
         self._n_admitted += 1
         self._q_tenant[req.tenant] = \
@@ -565,6 +566,7 @@ class Gateway:
         self._cancel_expired()
         while self._overflow and not self._queue_full():
             req = self._overflow.popleft()
+            req.wall_queued = time.perf_counter()
             self.cluster.enqueue(req)
             self._n_admitted += 1
             self._q_tenant[req.tenant] = \
@@ -735,7 +737,8 @@ class Gateway:
                 return
             head = cluster.central[0]
             d_hat = max(int(self.length.estimate(head)), 1)
-            a = self.policy.route(cluster, head, d_hat)
+            with tr_lib.span("gateway.route", rid=head.rid):
+                a = self.policy.route(cluster, head, d_hat)
             deferred = a is None or a >= cluster.m
             scores = None
             forced = False
@@ -825,49 +828,50 @@ class Gateway:
         on_tick = getattr(self.policy, "on_tick", None)
         on_run_end = getattr(self.policy, "on_run_end", None)
         while True:
-            self._apply_chaos()
-            self._update_health()
-            new: List[Tuple[Request, object]] = []
-            while i < n and stream[i][0].arrival <= cluster.t:
-                new.append(stream[i])
-                i += 1
-            if new:
-                self.length.prefetch(new)
-            self._drain_overflow()      # deferred clients retry first
-            self._drain_retries()       # elapsed-backoff crash orphans
-            for req, _ in new:
-                if tr.enabled:
-                    tr.emit(req.arrival, tr_lib.EV_ARRIVE, req.rid,
-                            -1, req.tenant,
-                            {"prompt": int(req.prompt_tokens)})
-                self._admit(req)
-            self._hedge_stuck()
-            if on_pre_route is not None:
-                # every request enqueued this tick (arrivals, drained
-                # overflow, retries, hedge requeues) is still in
-                # cluster.central here
-                on_pre_route(cluster)
-            self._route_some()
-            done_now = cluster.advance()
-            for r in done_now:
-                if self._inflight:
-                    self._inflight.pop(r.rid, None)
-                if track_health and r.instance is not None:
-                    self.health.on_complete(int(r.instance), r)
-                self.metrics.on_complete(r, r.tenant)
-            if on_tick is not None:
-                on_tick(cluster, done_now)
-            self._drain_overflow()
-            self._maybe_scale_up()
-            if tr.enabled and (cluster.t - self._last_counter
-                               >= cfg.trace_counter_every):
-                self._last_counter = cluster.t
-                self._sample_counters()
-            if (i >= n and not self._overflow and not self._retry_q
-                    and len(cluster.completed) >= self._n_admitted):
-                break
-            if cluster.t > cfg.max_time:
-                break
+            with tr_lib.span("gateway.tick"):
+                self._apply_chaos()
+                self._update_health()
+                new: List[Tuple[Request, object]] = []
+                while i < n and stream[i][0].arrival <= cluster.t:
+                    new.append(stream[i])
+                    i += 1
+                if new:
+                    self.length.prefetch(new)
+                self._drain_overflow()      # deferred clients retry first
+                self._drain_retries()       # elapsed-backoff crash orphans
+                for req, _ in new:
+                    if tr.enabled:
+                        tr.emit(req.arrival, tr_lib.EV_ARRIVE, req.rid,
+                                -1, req.tenant,
+                                {"prompt": int(req.prompt_tokens)})
+                    self._admit(req)
+                self._hedge_stuck()
+                if on_pre_route is not None:
+                    # every request enqueued this tick (arrivals, drained
+                    # overflow, retries, hedge requeues) is still in
+                    # cluster.central here
+                    on_pre_route(cluster)
+                self._route_some()
+                done_now = cluster.advance()
+                for r in done_now:
+                    if self._inflight:
+                        self._inflight.pop(r.rid, None)
+                    if track_health and r.instance is not None:
+                        self.health.on_complete(int(r.instance), r)
+                    self.metrics.on_complete(r, r.tenant)
+                if on_tick is not None:
+                    on_tick(cluster, done_now)
+                self._drain_overflow()
+                self._maybe_scale_up()
+                if tr.enabled and (cluster.t - self._last_counter
+                                   >= cfg.trace_counter_every):
+                    self._last_counter = cluster.t
+                    self._sample_counters()
+                if (i >= n and not self._overflow and not self._retry_q
+                        and len(cluster.completed) >= self._n_admitted):
+                    break
+                if cluster.t > cfg.max_time:
+                    break
         if on_run_end is not None:
             on_run_end()
         if getattr(cluster, "is_vec", False):

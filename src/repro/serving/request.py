@@ -57,6 +57,11 @@ class Request:
     cached_prefix: int = 0                   # prefill tokens served from cache
     retries: int = 0                         # crash-orphan re-admissions
     hedges: int = 0                          # hedged re-dispatches
+    # wall-clock stamps (time.perf_counter seconds) beside the virtual
+    # ones: put in the cluster's queue by the gateway, given a slot by an
+    # engine (cleared when the slot is lost; the queue stamp is kept)
+    wall_queued: Optional[float] = None
+    wall_started: Optional[float] = None
 
     # -- metrics -----------------------------------------------------------
     @property
@@ -87,6 +92,7 @@ class Request:
         self.cached_prefix = 0
         self.phase = Phase.PREEMPTED
         self.preemptions += 1
+        self.wall_started = None
 
     @property
     def total_context(self) -> int:

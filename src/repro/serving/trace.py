@@ -62,6 +62,13 @@ smaller t than its ``route``).  :func:`canonical` orders a stream by
 py-vs-vec parity tests assert: the backends iterate in different orders
 (instance-major vs round-major) but produce the same event *set* with
 bit-identical timestamps.
+
+Wall-clock spans (:func:`span`) are the other half: the served path's
+layer boundaries -- a gateway tick, a routing call, a cluster advance,
+an engine iteration, its prefill and decode, and the host's waits for
+token ids -- as ``jax.profiler.TraceAnnotation`` spans.  They land on
+the profiler's host plane, on the device trace's clock, only while a
+profiler runs; otherwise each costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -108,6 +115,22 @@ def canonical(events) -> List[Event]:
     comparison and export: (t, rid, lifecycle rank, instance)."""
     return sorted(events,
                   key=lambda e: (e[0], e[2], EVENT_ORDER[e[1]], e[3]))
+
+
+# -- wall-clock spans -------------------------------------------------------
+
+_annotation = None
+
+
+def span(name: str, **args):
+    """A context that records ``name`` (with ``args`` as its stats) on
+    the profiler's host plane while a profiler runs, and does nothing
+    otherwise.  It dispatches, transfers and waits for nothing.  JAX is
+    imported on first use, so the simulators stay free of it."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation as _annotation
+    return _annotation(name, **args)
 
 
 # -- recorders --------------------------------------------------------------
