@@ -189,11 +189,12 @@ def self_attention_prefill(p: Dict, cfg: ModelConfig, x: jax.Array,
 
 
 def self_attention_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
-                          position: jax.Array, cache: Dict):
+                          position: jax.Array, cache: Dict, layer):
     """Single-token decode.  x [B,1,d]; position [B] absolute position of the
-    new token; cache = {k [B,S,KV,hd], v [B,S,KV,hd]} with S = max len."""
-    k_cache, v_cache = cache["k"], cache["v"]
-    b, s_max = k_cache.shape[0], k_cache.shape[1]
+    new token; cache = {k [L,B,S,KV,hd], v [L,B,S,KV,hd]} (plus k_scale,
+    v_scale [L,B,S,KV] for int8) stacked over the layers, of which this is
+    ``layer``.  Returns (out, cache with the new K/V stored in place)."""
+    s_max = cache["k"].shape[2]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v_new = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -203,48 +204,27 @@ def self_attention_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
     pos = position[:, None]                     # [B,1]
     q = ops.apply_rope(q, pos, cfg.rope_theta)
     k_new = ops.apply_rope(k_new, pos, cfg.rope_theta)
-    if k_cache.shape[2] != k_new.shape[2]:      # expanded cache layout
+    if cache["k"].shape[3] != k_new.shape[2]:   # expanded cache layout
         k_new, v_new = _expand_kv_for_tp(cfg, k_new, v_new)
     from repro.distributed import context as dist_ctx
     q = dist_ctx.constrain_heads(q)
     int8_cache = cfg.kv_cache_dtype == "int8"
+    new = {"k": k_new, "v": v_new}
     if int8_cache:
-        k_new_q, k_new_s = quantize_kv(k_new)
-        v_new_q, v_new_s = quantize_kv(v_new)
-    # scatter the new K/V at `position`
-    onehot = jax.nn.one_hot(position, s_max, dtype=jnp.float32)  # [B,S]
-    oh = onehot[:, :, None, None]
-
-    def scatter(cache, new):
-        compute_dt = jnp.float32 if cache.dtype == jnp.int8 \
-            else cache.dtype
-        return (cache.astype(compute_dt) * (1 - oh).astype(compute_dt)
-                + oh.astype(compute_dt) * new.astype(compute_dt)
-                ).astype(cache.dtype)
-
+        new["k"], new["k_scale"] = quantize_kv(k_new)
+        new["v"], new["v_scale"] = quantize_kv(v_new)
+    cache = {name: ops.store_token(buf, layer, position, new[name])
+             for name, buf in cache.items()}
+    k_read, v_read = cache["k"][layer], cache["v"][layer]
     if int8_cache:
-        k_cache = scatter(k_cache, k_new_q)
-        v_cache = scatter(v_cache, v_new_q)
-        oh2 = onehot[:, :, None]
-        ks = (cache["k_scale"] * (1 - oh2) + oh2 * k_new_s
-              ).astype(cache["k_scale"].dtype)
-        vs = (cache["v_scale"] * (1 - oh2) + oh2 * v_new_s
-              ).astype(cache["v_scale"].dtype)
-        new_cache = {"k": k_cache, "v": v_cache, "k_scale": ks,
-                     "v_scale": vs}
-        k_read = dequantize_kv(k_cache, ks)
-        v_read = dequantize_kv(v_cache, vs)
-    else:
-        k_cache = scatter(k_cache, k_new)
-        v_cache = scatter(v_cache, v_new)
-        new_cache = {"k": k_cache, "v": v_cache}
-        k_read, v_read = k_cache, v_cache
+        k_read = dequantize_kv(k_read, cache["k_scale"][layer])
+        v_read = dequantize_kv(v_read, cache["v_scale"][layer])
     out = _maybe_pallas_decode(cfg, q, k_read, v_read, position + 1)
     if out is None:
         kv_pos = jnp.arange(s_max)[None, None, :]          # [1,1,S]
         mask = kv_pos <= position[:, None, None]           # [B,1,S]
         out = gqa_core(q, k_read, v_read, mask)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
 
 
 # ---------------------------------------------------------------------------

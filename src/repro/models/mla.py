@@ -99,19 +99,18 @@ def mla_prefill(p: Dict, cfg: ModelConfig, x: jax.Array,
 
 
 def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
-               position: jax.Array, cache: Dict):
-    """Absorbed decode: score/value computed in the latent space."""
+               position: jax.Array, cache: Dict, layer):
+    """Absorbed decode: score/value computed in the latent space.  cache =
+    {ckv [L,B,S,r], kr [L,B,S,rope]} stacked over the layers, of which this
+    is ``layer``; the new token's entries are stored into it in place."""
     m = cfg.mla
-    ckv_cache, kr_cache = cache["ckv"], cache["kr"]  # [B,S,r], [B,S,rope]
-    b, s_max, r = ckv_cache.shape
     pos = position[:, None]
     q_nope, q_rope = _project_q(p, cfg, x, pos)     # [B,1,H,*]
     ckv_new, kr_new = _project_kv_latent(p, cfg, x, pos)
-    onehot = jax.nn.one_hot(position, s_max, dtype=ckv_cache.dtype)
-    ckv_cache = ckv_cache * (1 - onehot[..., None]) + \
-        onehot[..., None] * ckv_new.astype(ckv_cache.dtype)
-    kr_cache = kr_cache * (1 - onehot[..., None]) + \
-        onehot[..., None] * kr_new.astype(kr_cache.dtype)
+    cache = {"ckv": ops.store_token(cache["ckv"], layer, position, ckv_new),
+             "kr": ops.store_token(cache["kr"], layer, position, kr_new)}
+    ckv_cache, kr_cache = cache["ckv"][layer], cache["kr"][layer]
+    s_max = ckv_cache.shape[1]
     # absorb W_kv_b(k-part) into q:  q_lat [B,1,H,r]
     wkb_k = p["wkv_b"][..., :m.qk_nope_head_dim]    # [r,H,nope]
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, wkb_k)
@@ -129,4 +128,4 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
     wkb_v = p["wkv_b"][..., m.qk_nope_head_dim:]       # [r,H,v]
     o = jnp.einsum("bqhr,rhv->bqhv", o_lat.astype(x.dtype), wkb_v)
     out = jnp.einsum("bshv,hvd->bsd", o, p["wo"])
-    return out, {"ckv": ckv_cache, "kr": kr_cache}
+    return out, cache

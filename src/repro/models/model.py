@@ -98,22 +98,30 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int) -> Dict:
 
 def _layer(p: Dict, cfg: ModelConfig, pos_in_period: int, x: jax.Array,
            positions: jax.Array, mode: str, cache_entry, vis: Optional[
-               jax.Array], cache_len: int):
-    """One layer.  Returns (x, new_cache_entry, aux)."""
+               jax.Array], cache_len: int, layer=None):
+    """One layer.  Returns (x, new_cache_entry, aux).
+
+    In decode mode ``cache_entry`` holds every layer of this kind stacked
+    on a leading axis and ``layer`` indexes this one: the layer writes only
+    what it changes into the stack and returns the stack."""
     kind = cfg.layer_kind(pos_in_period)
     h = ops.rmsnorm(x, p["ln1"], cfg.norm_eps)
     new_entry = cache_entry
     if kind == "mamba":
         if mode == "decode":
-            y, new_entry = mamba_mod.mamba_decode(p["mamba"], cfg, h,
-                                                  cache_entry)
+            y, state = mamba_mod.mamba_decode(
+                p["mamba"], cfg, h,
+                jax.tree.map(lambda a: a[layer], cache_entry))
+            new_entry = jax.tree.map(
+                lambda a, s: a.at[layer].set(s.astype(a.dtype)),
+                cache_entry, state)
         else:
             y, states = mamba_mod.mamba_seq(p["mamba"], cfg, h)
             if mode == "prefill":
                 new_entry = states
     elif kind == "cross":
-        if mode == "decode":
-            vis_kv = cache_entry
+        if mode == "decode":                # read-only after prefill
+            vis_kv = jax.tree.map(lambda a: a[layer], cache_entry)
         else:
             vis_kv = attn.vision_kv(p["attn"], cfg, vis)
             if mode == "prefill":
@@ -128,7 +136,7 @@ def _layer(p: Dict, cfg: ModelConfig, pos_in_period: int, x: jax.Array,
                                                cache_len)
         else:
             y, new_entry = mla_mod.mla_decode(p["attn"], cfg, h, positions,
-                                              cache_entry)
+                                              cache_entry, layer)
     else:
         if mode == "train":
             y = attn.self_attention_train(p["attn"], cfg, h, positions)
@@ -136,8 +144,8 @@ def _layer(p: Dict, cfg: ModelConfig, pos_in_period: int, x: jax.Array,
             y, new_entry = attn.self_attention_prefill(p["attn"], cfg, h,
                                                        positions, cache_len)
         else:
-            y, new_entry = attn.self_attention_decode(p["attn"], cfg, h,
-                                                      positions, cache_entry)
+            y, new_entry = attn.self_attention_decode(
+                p["attn"], cfg, h, positions, cache_entry, layer)
     x = x + y
     aux = jnp.zeros((), jnp.float32)
     if "ln2" in p:
@@ -179,43 +187,61 @@ def _head(params: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 def _run_stack(params: Dict, cfg: ModelConfig, x: jax.Array,
                positions: jax.Array, mode: str, cache: Optional[Dict],
                vis: Optional[jax.Array], cache_len: int):
-    """Apply first_layer (if any) + the scanned periodic stack."""
+    """Apply first_layer (if any) + the scanned periodic stack.
+
+    Prefill stacks the layers' new cache entries as the scan's ``ys``.
+    Decode carries the stacked cache through the scan instead, so each
+    layer stores its one new token in place and the cache is never
+    sliced out and stacked back whole."""
     aux_total = jnp.zeros((), jnp.float32)
     new_cache = dict(cache) if cache is not None else None
+    decode = mode == "decode"
     if cfg.dense_first_layer:
-        entry = cache.get("first_layer") if cache else None
+        entry = None
+        if decode:                          # a stack of one layer
+            entry = jax.tree.map(lambda a: a[None], cache["first_layer"])
         x, new_entry, aux = _layer(params["first_layer"], cfg, 0, x,
-                                   positions, mode, entry, vis, cache_len)
+                                   positions, mode, entry, vis, cache_len,
+                                   layer=0)
         aux_total += aux
-        if new_cache is not None and mode in ("prefill", "decode"):
+        if decode:
+            new_cache["first_layer"] = jax.tree.map(lambda a: a[0],
+                                                    new_entry)
+        elif mode == "prefill":
             new_cache["first_layer"] = new_entry
 
     from repro.distributed import context as dist_ctx
 
     def body(carry, xs):
-        h, aux_acc = carry
+        h, aux_acc, entries = carry
         h = dist_ctx.constrain_batch(h)
-        layer_params, layer_cache = xs
+        layer_params, i = xs
         new_entries = []
         for pos in range(cfg.period):
-            entry = None if layer_cache is None else layer_cache[pos]
+            entry = None if entries is None else entries[pos]
             h, new_entry, aux = _layer(layer_params[pos], cfg, pos, h,
                                        positions, mode, entry, vis,
-                                       cache_len)
+                                       cache_len, layer=i)
             new_entries.append(new_entry)
         h = dist_ctx.constrain_batch(h)
-        return (h, aux_acc + aux), (new_entries if mode != "train" else 0)
+        if decode:
+            return (h, aux_acc + aux, new_entries), None
+        return (h, aux_acc + aux, None), (
+            new_entries if mode == "prefill" else None)
 
     if cfg.remat and mode == "train":
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
-    layer_cache_xs = None if cache is None else cache["layers"]
-    xs = (params["layers"], layer_cache_xs)
-    (x, aux_total), cache_out = jax.lax.scan(
-        body, (x, aux_total), xs, unroll=True if cfg.scan_unroll else 1)
-    if new_cache is not None and mode in ("prefill", "decode"):
-        new_cache["layers"] = cache_out
+    carried = cache["layers"] if decode else None
+    (x, aux_total, carried), stacked = jax.lax.scan(
+        body, (x, aux_total, carried),
+        (params["layers"], jnp.arange(cfg.n_periods)),
+        unroll=True if cfg.scan_unroll else 1)
+    if decode:
+        new_cache["layers"] = carried
+    elif mode == "prefill":
+        new_cache["layers"] = stacked
     return x, new_cache, aux_total
 
 

@@ -49,6 +49,19 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
     return cap * jnp.tanh(x / cap)
 
 
+def store_token(buf: jax.Array, layer, position: jax.Array,
+                new: jax.Array) -> jax.Array:
+    """Write one decoded token per row into a layer-stacked cache.
+
+    buf [L, B, S, ...] holds every layer of the stack; new [B, 1, ...] is
+    row b's entry for position[b] of ``layer``.  Only those B entries are
+    written, in place.  A row whose position is past S writes nothing:
+    free slots keep counting, so clamping would overwrite a live entry."""
+    rows = jnp.arange(buf.shape[1])
+    return buf.at[layer, rows, position].set(
+        new[:, 0].astype(buf.dtype), mode="drop", unique_indices=True)
+
+
 def causal_mask(q_len: int, kv_len: int, q_offset) -> jax.Array:
     """Boolean mask [q_len, kv_len]; True where attention is allowed.
 

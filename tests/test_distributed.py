@@ -79,6 +79,46 @@ def test_ep_moe_matches_ragged(subproc):
     assert float(out.split("AUXERR")[1]) < 0.3
 
 
+def test_decode_tp_expanded_cache(subproc):
+    """KV heads that do not divide the model axis are stored expanded to
+    every query head; decode stores each token into that stacked cache
+    and gives the logits and K/V of the unsharded step."""
+    out = subproc("""
+        import jax, jax.numpy as jnp
+        from repro.configs import REGISTRY
+        from repro.models import model as M, params as P
+        from repro.distributed import context as dist_ctx
+        from repro.launch.mesh import make_mesh
+        cfg = REGISTRY['qwen3-0.6b'].reduced(n_kv_heads=1)
+        pr = P.init_params(jax.random.PRNGKey(0), cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0,
+                                  cfg.vocab_size)
+
+        def serve():
+            _, cache = M.prefill(pr, cfg, tokens=toks[:, :3], cache_len=8)
+            step = jax.jit(lambda c, t: M.decode_step(pr, cfg, c, tokens=t))
+            lgs = []
+            for t in range(3, 6):
+                lg, cache = step(cache, toks[:, t])
+                lgs.append(lg)
+            return jnp.stack(lgs), cache
+
+        ref_lg, ref_cache = serve()
+        mesh = make_mesh((2, 2), ('data', 'model'))
+        ctx = dist_ctx.ParallelContext(mesh=mesh, batch_axes=('data',),
+                                       model_axis='model')
+        with dist_ctx.use(ctx), jax.set_mesh(mesh):
+            lg, cache = serve()
+        k, ref_k = cache['layers'][0]['k'], ref_cache['layers'][0]['k']
+        print('HEADS', k.shape[3], ref_k.shape[3])
+        print('LGERR', float(jnp.abs(lg - ref_lg).max()))
+        print('KERR', float(jnp.abs(k - jnp.repeat(ref_k, 4, axis=3)).max()))
+    """)
+    assert out.split("HEADS")[1].split()[:2] == ["4", "1"]
+    assert float(out.split("LGERR")[1].split()[0]) < 1e-4
+    assert float(out.split("KERR")[1].split()[0]) < 1e-5
+
+
 def test_moe_gather_matches_dense():
     import jax
     from repro.configs import REGISTRY

@@ -1,4 +1,5 @@
-"""Compile the Pallas kernels of the serving path for a described TPU v5e.
+"""Compile the serving path's Pallas kernels and decode step for a
+described TPU v5e.
 
 Nothing runs: the TPU compiler, which is installed with jax, compiles for
 a chip that is described and not attached.  A kernel that interpret mode
@@ -7,8 +8,10 @@ tiling, too much fast memory), so these tests guard what the CPU kernel
 tests cannot.  The topology is described inside a fixture, never at
 import, so every test worker collects the same tests.
 """
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +21,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.decode_attention.kernel import decode_attention_kernel
 from repro.kernels.flash_attention import ops as fa
+from repro.models import model as model_lib
+from repro.models import params as params_lib
+from repro.serving import engine
 
 QWEN3 = get_config("qwen3-0.6b")
 
@@ -67,3 +73,38 @@ def test_flash_op_compiles_for_v5e(one_chip, sq):
                one_chip, ((1, sq, h, hd), bf16), ((1, sq, kv, hd), bf16),
                ((1, sq, kv, hd), bf16))
     assert "tpu_custom_call" in hlo
+
+
+def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch):
+    """The engine's decode step at published widths (16 slots x 2048, the
+    cache donated) stores each token's K/V into the stacked cache in
+    place: no whole-cache temporaries and no copy of the whole cache."""
+    # the decode op chooses interpret mode by the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(QWEN3, use_pallas=True)
+    slots, cache_len = 16, 2048
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: params_lib.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: model_lib.init_cache(cfg, slots, cache_len)))
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    decode = engine._build_fns(cfg, cache_len)[2]
+    compiled = decode.lower(params, cache, toks).compile()
+    mem = compiled.memory_analysis()
+    kv_bytes = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(cache["layers"]))
+    assert kv_bytes > 3.7e9                       # 3.76 GB of bf16 K/V
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.alias_size_in_bytes >= 3.7e9
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    stacked = "bf16[%s]" % ",".join(map(str, cache["layers"][0]["k"].shape))
+    assert stacked == "bf16[28,16,2048,8,128]"
+    copies = re.findall(r"(%\S+) = " + re.escape(stacked)
+                        + r"\{[^}]*\} copy\(", hlo)
+    assert not copies, copies
