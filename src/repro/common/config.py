@@ -21,17 +21,51 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     impl: str = "ragged"     # "dense" | "ragged" | "ep" (EP shard_map)
+    # top-k weights: renormalised to sum to 1, or (deepseek-v2) the raw
+    # softmax scores times ``routed_scaling``
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
+    # the experts this replica holds of an expert-parallel deployment:
+    # ``n_held`` experts from ``held_start`` (0 = all ``n_experts``).
+    # The router still scores all of them; pairs routed elsewhere add
+    # nothing here (the absent ranks' share).
+    n_held: int = 0
+    held_start: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
     """Multi-head latent attention (DeepSeek-V2 / MiniCPM3 style)."""
 
-    q_lora_rank: int = 768
+    q_lora_rank: int = 768        # 0: a direct query projection wq
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+    # deepseek-v2: the rotary dims come as interleaved pairs, permuted to
+    # rotate-half order before the rotation
+    rope_interleaved: bool = False
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 applies it:
+    frequencies blended between interpolated and original across the
+    dims whose wavelength falls between ``beta_fast`` and ``beta_slow``
+    rotations of ``original_max_position``; cos/sin scaled by
+    mscale(mscale) / mscale(mscale_all_dim), the softmax scale by
+    mscale(mscale_all_dim) squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -67,6 +101,7 @@ class ModelConfig:
     attention: str = "gqa"        # gqa | mla | none
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    yarn: Optional[YaRNConfig] = None   # YaRN rope scaling (MLA only)
     norm_eps: float = 1e-6
     logit_softcap: float = 0.0   # gemma/grok final-logit softcap (0=off)
     scale_embeddings: bool = False  # gemma: multiply embeddings by sqrt(d)
@@ -162,14 +197,26 @@ class ModelConfig:
         if self.dense_first_layer:
             changes["dense_first_d_ff"] = 128
         if self.moe is not None:
+            moe = dict(n_experts=min(self.moe.n_experts, 4),
+                       top_k=min(self.moe.top_k, 2))
+            if self.moe.n_held:
+                # a quarter share of 8 experts, from the same rank
+                rank = self.moe.held_start // self.moe.n_held
+                moe = dict(n_experts=8, top_k=min(self.moe.top_k, 3),
+                           n_held=2, held_start=2 * rank)
             changes["moe"] = dataclasses.replace(
-                self.moe, n_experts=min(self.moe.n_experts, 4),
-                top_k=min(self.moe.top_k, 2), d_expert=32,
-                n_shared=min(self.moe.n_shared, 1), impl="ragged")
+                self.moe, d_expert=32, n_shared=min(self.moe.n_shared, 1),
+                impl="ragged", **moe)
         if self.mla is not None:
-            changes["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
-                                       qk_nope_head_dim=16, qk_rope_head_dim=8,
-                                       v_head_dim=16)
+            changes["mla"] = dataclasses.replace(
+                self.mla, q_lora_rank=32 if self.mla.q_lora_rank else 0,
+                kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16)
+        if self.yarn is not None:
+            # positions past 32 leave the original window, as 4096 does
+            # at published size
+            changes["yarn"] = dataclasses.replace(
+                self.yarn, original_max_position=32)
         if self.mamba is not None:
             changes["mamba"] = dataclasses.replace(
                 self.mamba, d_inner=128, d_state=8, dt_rank=8, chunk=16)

@@ -12,6 +12,7 @@ from repro.configs.qwen3_0_6b import CONFIG as _qwen3
 from repro.configs.falcon_mamba_7b import CONFIG as _falcon_mamba
 from repro.configs.grok_1_314b import CONFIG as _grok
 from repro.configs.deepseek_moe_16b import CONFIG as _deepseek
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 from repro.configs.musicgen_medium import CONFIG as _musicgen
 from repro.configs.llama_3_2_vision_11b import CONFIG as _llama_vision
 from repro.configs.jamba_v0_1_52b import CONFIG as _jamba
@@ -25,13 +26,16 @@ REGISTRY: Dict[str, ModelConfig] = {
     "falcon-mamba-7b": _falcon_mamba,
     "grok-1-314b": _grok,
     "deepseek-moe-16b": _deepseek,
+    "deepseek-v2-lite": _deepseek_v2_lite,
     "musicgen-medium": _musicgen,
     "llama-3.2-vision-11b": _llama_vision,
     "jamba-v0.1-52b": _jamba,
     "llama-2-7b": _llama2,
 }
 
-ASSIGNED = tuple(k for k in REGISTRY if k != "llama-2-7b")
+# the seed's dry-run set; deepseek-v2-lite is a served chip share
+ASSIGNED = tuple(k for k in REGISTRY
+                 if k not in ("llama-2-7b", "deepseek-v2-lite"))
 
 
 def get_config(name: str) -> ModelConfig:

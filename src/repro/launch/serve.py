@@ -158,7 +158,7 @@ def warm_fleet(engines, prompt_lengths) -> None:
         _, small = prefill((e, lengths[0]))
         e.cache = e.insert_fn(e.cache, small, 0)
         toks = jax.device_put(e.next_tokens, e.device)
-        _, e.cache = e.decode_fn(e.params, e.cache, toks)
+        e.cache = e.decode_fn(e.params, e.cache, toks)[1]
         jax.block_until_ready(e.cache)
 
     with ThreadPoolExecutor(os.cpu_count()) as ex:

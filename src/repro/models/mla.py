@@ -15,14 +15,32 @@ from repro.common.config import ModelConfig
 from repro.models import ops
 
 
+def _rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array):
+    return ops.apply_rope(x, positions, cfg.rope_theta, cfg.yarn,
+                          cfg.mla.rope_interleaved)
+
+
+def _scale(cfg: ModelConfig):
+    """Softmax scale: 1/sqrt(q.k width), times YaRN's temperature factor
+    squared where the config scales its rope."""
+    m = cfg.mla
+    scale = 1.0 / jnp.sqrt(float(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    if cfg.yarn is not None:
+        scale = scale * ops.yarn_mscale(cfg.yarn.factor,
+                                        cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def _project_q(p: Dict, cfg: ModelConfig, x: jax.Array,
                positions: jax.Array):
     m = cfg.mla
-    q_lat = ops.rmsnorm(x @ p["wq_a"], p["q_norm_a"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])
+    if m.q_lora_rank:
+        q_lat = ops.rmsnorm(x @ p["wq_a"], p["q_norm_a"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope = q[..., :m.qk_nope_head_dim]
-    q_rope = ops.apply_rope(q[..., m.qk_nope_head_dim:], positions,
-                            cfg.rope_theta)
+    q_rope = _rope(cfg, q[..., m.qk_nope_head_dim:], positions)
     return q_nope, q_rope
 
 
@@ -32,8 +50,7 @@ def _project_kv_latent(p: Dict, cfg: ModelConfig, x: jax.Array,
     m = cfg.mla
     lat = x @ p["wkv_a"]
     ckv = ops.rmsnorm(lat[..., :m.kv_lora_rank], p["kv_norm_a"], cfg.norm_eps)
-    k_rope = ops.apply_rope(lat[..., None, m.kv_lora_rank:], positions,
-                            cfg.rope_theta)[..., 0, :]
+    k_rope = _rope(cfg, lat[..., None, m.kv_lora_rank:], positions)[..., 0, :]
     return ckv, k_rope
 
 
@@ -53,7 +70,7 @@ def mla_prefill(p: Dict, cfg: ModelConfig, x: jax.Array,
     kv = jnp.einsum("bsr,rhk->bshk", ckv, p["wkv_b"])
     k_nope = kv[..., :m.qk_nope_head_dim]
     v = kv[..., m.qk_nope_head_dim:]
-    scale = 1.0 / jnp.sqrt(float(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = _scale(cfg)
     kn_f = k_nope.astype(jnp.float32)
     kr_f = k_rope.astype(jnp.float32)
     v_f = v.astype(jnp.float32)
@@ -114,7 +131,7 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
     # absorb W_kv_b(k-part) into q:  q_lat [B,1,H,r]
     wkb_k = p["wkv_b"][..., :m.qk_nope_head_dim]    # [r,H,nope]
     q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, wkb_k)
-    scale = 1.0 / jnp.sqrt(float(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = _scale(cfg)
     scores = (jnp.einsum("bqhr,bsr->bhqs", q_lat.astype(jnp.float32),
                          ckv_cache.astype(jnp.float32))
               + jnp.einsum("bqhk,bsk->bhqs", q_rope.astype(jnp.float32),

@@ -99,11 +99,13 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int) -> Dict:
 def _layer(p: Dict, cfg: ModelConfig, pos_in_period: int, x: jax.Array,
            positions: jax.Array, mode: str, cache_entry, vis: Optional[
                jax.Array], cache_len: int, layer=None):
-    """One layer.  Returns (x, new_cache_entry, aux).
+    """One layer.  Returns (x, new_cache_entry, aux, counts).
 
     In decode mode ``cache_entry`` holds every layer of this kind stacked
     on a leading axis and ``layer`` indexes this one: the layer writes only
-    what it changes into the stack and returns the stack."""
+    what it changes into the stack and returns the stack.  ``counts`` is
+    the tokens routed to each held expert in a decoded MoE layer
+    (``moe.held_counts``), else None."""
     kind = cfg.layer_kind(pos_in_period)
     h = ops.rmsnorm(x, p["ln1"], cfg.norm_eps)
     new_entry = cache_entry
@@ -148,17 +150,21 @@ def _layer(p: Dict, cfg: ModelConfig, pos_in_period: int, x: jax.Array,
                 p["attn"], cfg, h, positions, cache_entry, layer)
     x = x + y
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     if "ln2" in p:
         h2 = ops.rmsnorm(x, p["ln2"], cfg.norm_eps)
         if "moe" in p:
             y2, aux = moe_mod.moe_layer(p["moe"], cfg, h2)
+            if mode == "decode":
+                counts = moe_mod.held_counts(
+                    p["moe"], cfg, h2.reshape(-1, h2.shape[-1]))
         else:
             y2 = ffn_forward(p["ffn"], cfg, h2)
         if kind == "cross":
             y2 = y2 * jnp.tanh(
                 p["gate_ffn"].astype(jnp.float32)).astype(y2.dtype)
         x = x + y2
-    return x, new_entry, aux
+    return x, new_entry, aux, counts
 
 
 # ---------------------------------------------------------------------------
@@ -192,7 +198,9 @@ def _run_stack(params: Dict, cfg: ModelConfig, x: jax.Array,
     Prefill stacks the layers' new cache entries as the scan's ``ys``.
     Decode carries the stacked cache through the scan instead, so each
     layer stores its one new token in place and the cache is never
-    sliced out and stacked back whole."""
+    sliced out and stacked back whole; its ``ys`` are the scanned MoE
+    layers' routing counts.  Returns (x, cache, aux, counts): counts
+    int32 [MoE layers, held] in decode, else None."""
     aux_total = jnp.zeros((), jnp.float32)
     new_cache = dict(cache) if cache is not None else None
     decode = mode == "decode"
@@ -200,9 +208,9 @@ def _run_stack(params: Dict, cfg: ModelConfig, x: jax.Array,
         entry = None
         if decode:                          # a stack of one layer
             entry = jax.tree.map(lambda a: a[None], cache["first_layer"])
-        x, new_entry, aux = _layer(params["first_layer"], cfg, 0, x,
-                                   positions, mode, entry, vis, cache_len,
-                                   layer=0)
+        x, new_entry, aux, _ = _layer(params["first_layer"], cfg, 0, x,
+                                      positions, mode, entry, vis,
+                                      cache_len, layer=0)
         aux_total += aux
         if decode:
             new_cache["first_layer"] = jax.tree.map(lambda a: a[0],
@@ -216,16 +224,19 @@ def _run_stack(params: Dict, cfg: ModelConfig, x: jax.Array,
         h, aux_acc, entries = carry
         h = dist_ctx.constrain_batch(h)
         layer_params, i = xs
-        new_entries = []
+        new_entries, counts = [], []
         for pos in range(cfg.period):
             entry = None if entries is None else entries[pos]
-            h, new_entry, aux = _layer(layer_params[pos], cfg, pos, h,
-                                       positions, mode, entry, vis,
-                                       cache_len, layer=i)
+            h, new_entry, aux, c = _layer(layer_params[pos], cfg, pos, h,
+                                          positions, mode, entry, vis,
+                                          cache_len, layer=i)
             new_entries.append(new_entry)
+            if c is not None:
+                counts.append(c)
         h = dist_ctx.constrain_batch(h)
         if decode:
-            return (h, aux_acc + aux, new_entries), None
+            return (h, aux_acc + aux, new_entries), (
+                jnp.stack(counts) if counts else None)
         return (h, aux_acc + aux, None), (
             new_entries if mode == "prefill" else None)
 
@@ -238,11 +249,14 @@ def _run_stack(params: Dict, cfg: ModelConfig, x: jax.Array,
         body, (x, aux_total, carried),
         (params["layers"], jnp.arange(cfg.n_periods)),
         unroll=True if cfg.scan_unroll else 1)
+    counts = None
     if decode:
         new_cache["layers"] = carried
+        if stacked is not None:
+            counts = stacked.reshape(-1, stacked.shape[-1])
     elif mode == "prefill":
         new_cache["layers"] = stacked
-    return x, new_cache, aux_total
+    return x, new_cache, aux_total, counts
 
 
 def forward_train(params: Dict, cfg: ModelConfig, tokens=None,
@@ -256,7 +270,8 @@ def forward_train(params: Dict, cfg: ModelConfig, tokens=None,
         vis = vision.astype(x.dtype) @ params["vision_proj"]
     else:
         vis = None
-    x, _, aux = _run_stack(params, cfg, x, positions, "train", None, vis, s)
+    x, _, aux, _ = _run_stack(params, cfg, x, positions, "train", None, vis,
+                              s)
     return _head(params, cfg, x), aux
 
 
@@ -274,8 +289,8 @@ def prefill(params: Dict, cfg: ModelConfig, tokens=None, embeds=None,
     else:
         vis = None
     cache = init_cache(cfg, b, cache_len)
-    x, cache, _ = _run_stack(params, cfg, x, positions, "prefill", cache,
-                             vis, cache_len)
+    x, cache, _, _ = _run_stack(params, cfg, x, positions, "prefill",
+                                cache, vis, cache_len)
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
     cache["pos"] = lengths
@@ -284,19 +299,22 @@ def prefill(params: Dict, cfg: ModelConfig, tokens=None, embeds=None,
 
 
 def decode_step(params: Dict, cfg: ModelConfig, cache: Dict, tokens=None,
-                embeds=None):
+                embeds=None, routed: bool = False):
     """One decode step for the whole batch.  tokens [B] (or embeds [B,1,d]).
 
-    Returns (logits [B,V], new_cache)."""
+    Returns (logits [B,V], new_cache), and with ``routed`` (MoE configs)
+    also the tokens routed to each held expert, int32 [MoE layers, held]
+    (every row of the batch counts)."""
     if tokens is not None:
         x = _embed_inputs(params, cfg, tokens[:, None], None)
     else:
         x = _embed_inputs(params, cfg, None, embeds)
     positions = cache["pos"]                        # [B]
-    x, cache, _ = _run_stack(params, cfg, x, positions, "decode", cache,
-                             None, 0)
+    x, cache, _, counts = _run_stack(params, cfg, x, positions, "decode",
+                                     cache, None, 0)
     cache["pos"] = positions + 1
-    return _head(params, cfg, x)[:, 0], cache
+    out = (_head(params, cfg, x)[:, 0], cache)
+    return out + (counts,) if routed else out
 
 
 def forward_hidden(params: Dict, cfg: ModelConfig, tokens=None,
@@ -310,7 +328,8 @@ def forward_hidden(params: Dict, cfg: ModelConfig, tokens=None,
         vis = vision.astype(x.dtype) @ params["vision_proj"]
     else:
         vis = None
-    x, _, aux = _run_stack(params, cfg, x, positions, "train", None, vis, s)
+    x, _, aux, _ = _run_stack(params, cfg, x, positions, "train", None, vis,
+                              s)
     return ops.rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
 
 

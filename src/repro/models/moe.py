@@ -16,6 +16,12 @@ Three interchangeable implementations (cfg.moe.impl):
 The routed output is combined with the shared-expert output (computed by the
 caller as a dense FFN under auto sharding) and carries a load-balance aux
 loss (switch-transformer style).
+
+A replica may hold a share of the experts (``MoEConfig.n_held`` from
+``held_start``: one rank of an expert-parallel deployment, without its
+exchange).  The router scores all experts; dense, ragged and gather
+compute the held experts' part of the result, and pairs routed to experts
+held elsewhere add nothing.
 """
 from __future__ import annotations
 
@@ -31,13 +37,41 @@ from repro.models import ops
 
 
 def _router(p: Dict, x: jax.Array, cfg: ModelConfig):
-    """x [T,d] -> (probs [T,E] f32, topk_idx [T,k], topk_w [T,k] f32)."""
+    """x [T,d] -> (probs [T,E] f32, topk_idx [T,k], topk_w [T,k] f32),
+    over all E experts whichever this replica holds."""
     m = cfg.moe
     logits = (x.astype(jnp.float32) @ p["router"])
     probs = jax.nn.softmax(logits, axis=-1)
     topk_w, topk_idx = jax.lax.top_k(probs, m.top_k)
-    topk_w = topk_w / jnp.maximum(jnp.sum(topk_w, -1, keepdims=True), 1e-9)
+    if m.norm_topk:
+        topk_w = topk_w / jnp.maximum(jnp.sum(topk_w, -1, keepdims=True),
+                                      1e-9)
+    else:
+        topk_w = topk_w * m.routed_scaling
     return probs, topk_idx, topk_w
+
+
+def _held_pairs(cfg: ModelConfig, topk_idx: jax.Array, topk_w: jax.Array):
+    """Each (token, expert) pair as this replica's experts see it: the
+    index among the held experts and the weight.  A pair routed to an
+    expert held elsewhere gets index ``held`` (one past the last) and
+    weight 0: it adds nothing here."""
+    m = cfg.moe
+    if m.held == m.n_experts:
+        return topk_idx, topk_w
+    local = topk_idx - m.held_start
+    here = (local >= 0) & (local < m.held)
+    return (jnp.where(here, local, m.held),
+            jnp.where(here, topk_w, 0.0))
+
+
+def held_counts(p: Dict, cfg: ModelConfig, x_flat: jax.Array) -> jax.Array:
+    """Tokens of ``x_flat`` [T,d] routed to each held expert: int32
+    [held]."""
+    _, topk_idx, topk_w = _router(p, x_flat, cfg)
+    local, _ = _held_pairs(cfg, topk_idx, topk_w)
+    return jnp.bincount(local.reshape(-1), length=cfg.moe.held
+                        ).astype(jnp.int32)
 
 
 def aux_loss(probs: jax.Array, topk_idx: jax.Array, n_experts: int):
@@ -51,7 +85,7 @@ def aux_loss(probs: jax.Array, topk_idx: jax.Array, n_experts: int):
 
 def _unique_experts(w: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Strip EP replication from stored expert weights (for non-EP math)."""
-    e = cfg.moe.n_experts
+    e = cfg.moe.held
     if w.shape[0] == e:
         return w
     r = w.shape[0] // e
@@ -74,14 +108,12 @@ def _expert_ffn_dense(p: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def moe_dense(p: Dict, cfg: ModelConfig, x_flat: jax.Array):
     probs, topk_idx, topk_w = _router(p, x_flat, cfg)
-    y_all = _expert_ffn_dense(p["routed"], cfg, x_flat)     # [T,E,d]
-    combine = jnp.zeros(probs.shape, x_flat.dtype)
-    combine = jnp.take_along_axis(
-        combine, topk_idx, axis=1)  # placeholder shape [T,k]
-    # scatter topk weights into [T,E]
-    comb = jnp.zeros(probs.shape, jnp.float32)
+    local, w = _held_pairs(cfg, topk_idx, topk_w)
+    y_all = _expert_ffn_dense(p["routed"], cfg, x_flat)     # [T,held,d]
+    # scatter topk weights into [T,held]; pairs held elsewhere drop out
+    comb = jnp.zeros((x_flat.shape[0], cfg.moe.held), jnp.float32)
     comb = comb.at[jnp.arange(x_flat.shape[0])[:, None],
-                   topk_idx].set(topk_w)
+                   local].add(w, mode="drop")
     y = jnp.einsum("te,ted->td", comb.astype(x_flat.dtype), y_all)
     return y, aux_loss(probs, topk_idx, cfg.moe.n_experts)
 
@@ -107,16 +139,22 @@ def moe_ragged(p: Dict, cfg: ModelConfig, x_flat: jax.Array):
     m = cfg.moe
     t = x_flat.shape[0]
     probs, topk_idx, topk_w = _router(p, x_flat, cfg)
-    flat_expert = topk_idx.reshape(-1)                      # [T*k]
+    local, w = _held_pairs(cfg, topk_idx, topk_w)
+    # pairs held elsewhere sort last, past every group (masked below)
+    flat_expert = local.reshape(-1)                         # [T*k]
     order = jnp.argsort(flat_expert)
     token_of_pair = jnp.arange(t * m.top_k) // m.top_k
     x_sorted = x_flat[token_of_pair[order]]
-    group_sizes = jnp.bincount(flat_expert, length=m.n_experts)
+    group_sizes = jnp.bincount(flat_expert, length=m.held)
     y_sorted = _grouped_ffn(p["routed"], cfg, x_sorted, group_sizes)
     # unsort and weighted-combine the k copies
     inv = jnp.argsort(order)
     y_pairs = y_sorted[inv].reshape(t, m.top_k, -1)
-    y = jnp.sum(y_pairs * topk_w[..., None].astype(y_pairs.dtype), axis=1)
+    if m.held != m.n_experts:
+        # what ragged_dot leaves in rows past the groups is not defined
+        # on every backend: select, as 0 * NaN would not be 0
+        y_pairs = jnp.where((local < m.held)[..., None], y_pairs, 0)
+    y = jnp.sum(y_pairs * w[..., None].astype(y_pairs.dtype), axis=1)
     return y.astype(x_flat.dtype), aux_loss(probs, topk_idx, m.n_experts)
 
 
@@ -203,6 +241,9 @@ def moe_ep(p: Dict, cfg: ModelConfig, x_flat: jax.Array):
     ctx = dist_ctx.get()
     mesh = ctx.mesh
     assert mesh is not None, "EP MoE requires a parallel context mesh"
+    if cfg.moe.held != cfg.moe.n_experts:
+        raise ValueError("the ep path shards all experts; a replica that "
+                         "holds a share runs ragged, gather or dense")
     ep_axes = ctx.ep_axes or ("data",)
     batch_axes = ctx.batch_axes or ep_axes
     tp_axis = ctx.model_axis
@@ -236,21 +277,23 @@ def moe_gather(p: Dict, cfg: ModelConfig, x_flat: jax.Array):
     m = cfg.moe
     t = x_flat.shape[0]
     probs, topk_idx, topk_w = _router(p, x_flat, cfg)
+    # a pair held elsewhere reads the last held expert, at weight 0
+    local, w = _held_pairs(cfg, topk_idx, topk_w)
     act = ops.activation(cfg.activation)
     w_up = _unique_experts(p["routed"]["w_up"], cfg)
     w_down = _unique_experts(p["routed"]["w_down"], cfg)
     w_gate = _unique_experts(p["routed"].get("w_gate",
                                              p["routed"]["w_up"]), cfg)
-    wu = jnp.take(w_up, topk_idx, axis=0)        # [T,k,d,f]
-    wd = jnp.take(w_down, topk_idx, axis=0)      # [T,k,f,d]
+    wu = jnp.take(w_up, local, axis=0, mode="clip")      # [T,k,d,f]
+    wd = jnp.take(w_down, local, axis=0, mode="clip")    # [T,k,f,d]
     h = jnp.einsum("td,tkdf->tkf", x_flat, wu)
     if cfg.gated_mlp:
-        wg = jnp.take(w_gate, topk_idx, axis=0)
+        wg = jnp.take(w_gate, local, axis=0, mode="clip")
         h = act(jnp.einsum("td,tkdf->tkf", x_flat, wg)) * h
     else:
         h = act(h)
     y = jnp.einsum("tkf,tkfd->tkd", h, wd)
-    y = jnp.sum(y * topk_w[..., None].astype(y.dtype), axis=1)
+    y = jnp.sum(y * w[..., None].astype(y.dtype), axis=1)
     return y.astype(x_flat.dtype), aux_loss(probs, topk_idx, m.n_experts)
 
 

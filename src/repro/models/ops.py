@@ -1,6 +1,8 @@
 """Primitive neural ops shared across the model zoo (pure jnp)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -21,24 +23,60 @@ def activation(name: str):
     }[name]
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    """Inverse frequencies for rotary embeddings (half the head dim)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor for a context ``factor``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse frequencies for rotary embeddings (half the head dim).
+
+    With ``yarn`` (a ``YaRNConfig``) each frequency is blended between
+    itself and itself over ``yarn.factor``: kept where it turns more than
+    ``beta_fast`` times over ``original_max_position``, interpolated
+    where it turns fewer than ``beta_slow`` times, linearly between."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponent)
+    freqs = 1.0 / (theta ** exponent)
+    if yarn is None:
+        return freqs
+
+    def dim_of(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None, interleaved: bool = False) -> jax.Array:
     """Rotary position embedding.
 
     x: [..., seq, n_heads, head_dim]; positions: [..., seq] (int32).
+    ``yarn`` scales the frequencies and cos/sin (``rope_freqs``);
+    ``interleaved`` takes the head dim as pairs (x[2i], x[2i+1]) and
+    permutes them to rotate-half order first, as DeepSeek-V2 does.
     """
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                       # [hd/2]
+    freqs = rope_freqs(head_dim, theta, yarn)                 # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     # broadcast over heads: [..., S, 1, hd/2]
     angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        xf = xf.reshape(*x.shape[:-1], head_dim // 2, 2).swapaxes(
+            -1, -2).reshape(x.shape)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 

@@ -22,7 +22,12 @@ def _dtype(cfg: ModelConfig):
 def _dense(key, shape, dtype, fan_in=None):
     fan_in = fan_in if fan_in is not None else shape[0]
     scale = 1.0 / math.sqrt(max(fan_in, 1))
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    # the barrier keeps a compiled init from folding the scale into the
+    # normal's own constants: the same numbers whether drawn op by op or
+    # inside one program (init_params maps the layers)
+    x = jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, jnp.float32))
+    return (x * scale).astype(dtype)
 
 
 def _expert_storage(cfg: ModelConfig, data_shards: int) -> int:
@@ -30,9 +35,10 @@ def _expert_storage(cfg: ModelConfig, data_shards: int) -> int:
 
     For the expert-parallel path each of the ``data_shards`` devices owns one
     slot; experts are replicated ``R = shards // E`` times when E < shards
-    (grad symmetrization handles training).  For non-EP impls it is just E.
+    (grad symmetrization handles training).  For non-EP impls it is the
+    experts held here (all E unless the config holds a share).
     """
-    e = cfg.moe.n_experts
+    e = cfg.moe.held
     if cfg.moe.impl == "ep" and data_shards > 0:
         if e < data_shards:
             assert data_shards % e == 0, (e, data_shards)
@@ -85,11 +91,14 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
     ks = jax.random.split(key, 6)
     d, h, m = cfg.d_model, cfg.n_heads, cfg.mla
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    p = {
-        "wq_a": _dense(ks[0], (d, m.q_lora_rank), dtype),
-        "q_norm_a": jnp.zeros((m.q_lora_rank,), dtype),
-        "wq_b": _dense(ks[1], (m.q_lora_rank, h, qk), dtype,
-                       fan_in=m.q_lora_rank),
+    if m.q_lora_rank:
+        p = {"wq_a": _dense(ks[0], (d, m.q_lora_rank), dtype),
+             "q_norm_a": jnp.zeros((m.q_lora_rank,), dtype),
+             "wq_b": _dense(ks[1], (m.q_lora_rank, h, qk), dtype,
+                            fan_in=m.q_lora_rank)}
+    else:
+        p = {"wq": _dense(ks[0], (d, h, qk), dtype, fan_in=d)}
+    p.update({
         "wkv_a": _dense(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim),
                         dtype),
         "kv_norm_a": jnp.zeros((m.kv_lora_rank,), dtype),
@@ -98,7 +107,7 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
                         fan_in=m.kv_lora_rank),
         "wo": _dense(ks[4], (h, m.v_head_dim, d), dtype,
                      fan_in=h * m.v_head_dim),
-    }
+    })
     return p
 
 
@@ -106,7 +115,9 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
     ks = jax.random.split(key, 5)
     d, m, dtr = cfg.d_model, cfg.mamba, cfg.dt_rank
     di, ds, dc = m.d_inner, m.d_state, m.d_conv
-    a = jnp.tile(jnp.arange(1, ds + 1, dtype=jnp.float32)[None, :], (di, 1))
+    with jax.ensure_compile_time_eval():    # the same constant however
+        a_log = jnp.log(jnp.tile(             # the layers are mapped
+            jnp.arange(1, ds + 1, dtype=jnp.float32)[None, :], (di, 1)))
     return {
         "in_proj": _dense(ks[0], (d, 2 * di), dtype),
         "conv_w": _dense(ks[1], (dc, di), dtype, fan_in=dc),
@@ -114,7 +125,7 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
         "x_proj": _dense(ks[2], (di, dtr + 2 * ds), dtype, fan_in=di),
         "dt_proj": _dense(ks[3], (dtr, di), dtype, fan_in=dtr),
         "dt_bias": jnp.full((di,), -4.6, dtype),   # softplus^-1(0.01)
-        "A_log": jnp.log(a).astype(jnp.float32),
+        "A_log": a_log,
         "D": jnp.ones((di,), jnp.float32),
         "out_proj": _dense(ks[4], (di, d), dtype, fan_in=di),
     }
@@ -174,13 +185,14 @@ def init_params(key, cfg: ModelConfig, data_shards: int = 0) -> Dict[str, Any]:
                  "ffn": init_ffn(ks[3], cfg,
                                  cfg.dense_first_d_ff or cfg.d_ff, dtype)}
         params["first_layer"] = first
-    # stacked per-period-position layer params
+    # stacked per-period-position layer params, drawn one layer at a time
+    # so that no layer's float32 draws exist beside the others'
     layers = []
     for p_idx in range(cfg.period):
         def one(k):
             return init_layer(k, cfg, p_idx, dtype, data_shards)
         layer_keys = jax.random.split(ks[6 + p_idx], cfg.n_periods)
-        layers.append(jax.vmap(one)(layer_keys))
+        layers.append(jax.lax.map(one, layer_keys))
     params["layers"] = layers
     params["final_norm"] = jnp.zeros((cfg.d_model,), dtype)
     if not cfg.tie_embeddings or cfg.input_mode != "tokens":
@@ -213,8 +225,9 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         if cfg.attention == "mla":
             m = cfg.mla
             qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-            return (d * m.q_lora_rank + m.q_lora_rank * h * qk
-                    + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+            q = (d * m.q_lora_rank + m.q_lora_rank * h * qk
+                 if m.q_lora_rank else d * h * qk)
+            return (q + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     + m.kv_lora_rank * h * (m.qk_nope_head_dim
                                             + m.v_head_dim)
                     + h * m.v_head_dim * d
@@ -233,7 +246,7 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
     def moe_count():
         m = cfg.moe
         per = m.d_expert * d * (3 if cfg.gated_mlp else 2)
-        n_routed = m.top_k if active_only else m.n_experts
+        n_routed = m.top_k if active_only else m.held
         c = d * m.n_experts + n_routed * per
         if m.n_shared:
             c += ffn_count(m.n_shared * m.d_expert)

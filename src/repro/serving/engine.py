@@ -56,7 +56,9 @@ def _build_fns(cfg: ModelConfig, cache_len: int):
         return out
 
     def decode_step(params, cache, toks):
-        return model_lib.decode_step(params, cfg, cache, tokens=toks)
+        # MoE configs also return the tokens routed to each held expert
+        return model_lib.decode_step(params, cfg, cache, tokens=toks,
+                                     routed=cfg.moe is not None)
 
     return (jax.jit(prefill), jax.jit(insert, donate_argnums=(0,)),
             jax.jit(decode_step, donate_argnums=(1,)))
@@ -109,6 +111,11 @@ class LLMInstance:
         # index so lanes line up with the gateway's routing ids.
         self.trace = _trace.NULL
         self.trace_instance = instance_id
+        # running sums of what the decode steps routed (MoE configs):
+        # steps, (token, expert) pairs that reached a held expert, and
+        # held experts that at least one pair reached, summed over MoE
+        # layers; every row of a step counts, free slots too
+        self.moe_steps = self.moe_held_pairs = self.moe_experts_hit = 0
 
     # -- router-visible state ----------------------------------------------
     @property
@@ -227,9 +234,20 @@ class LLMInstance:
             return done
         with _trace.span("engine.decode"):
             toks = jax.device_put(self.next_tokens, self.device)
-            logits, self.cache = self.decode_fn(self.params, self.cache, toks)
-            with _trace.span("engine.fetch"):
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            if self.cfg.moe is None:
+                logits, self.cache = self.decode_fn(self.params, self.cache,
+                                                    toks)
+                with _trace.span("engine.fetch"):
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            else:
+                logits, self.cache, counts = self.decode_fn(
+                    self.params, self.cache, toks)
+                with _trace.span("engine.fetch"):    # one sync for both
+                    nxt, counts = jax.device_get(
+                        (jnp.argmax(logits, axis=-1), counts))
+                self.moe_steps += 1
+                self.moe_held_pairs += int(counts.sum())
+                self.moe_experts_hit += int(np.count_nonzero(counts))
             tr = self.trace
             for i in active:
                 r = self.slots[i]

@@ -792,13 +792,18 @@ class Gateway:
 
     def _sample_counters(self):
         """Counter-track samples for the Perfetto export: router queue
-        depth plus per-instance KV occupancy and outstanding backlog."""
+        depth plus per-instance KV occupancy and outstanding backlog,
+        and for an MoE engine its running routing sums."""
         tr = self.trace
         t = self.cluster.t
         tr.counter(t, "queue_depth", len(self.cluster.central))
         for i, inst in enumerate(self.cluster.instances):
             tr.counter(t, "kv_tokens", inst.resident_token_sum(), i)
             tr.counter(t, "backlog", inst.outstanding_tokens(), i)
+            eng = getattr(inst, "engine", None)
+            if eng is not None and eng.cfg.moe is not None:
+                tr.counter(t, "moe_held_pairs", eng.moe_held_pairs, i)
+                tr.counter(t, "moe_experts_hit", eng.moe_experts_hit, i)
 
     # -- serving loop --------------------------------------------------
     def run(self, scenario_or_requests, samples=None) -> Dict:

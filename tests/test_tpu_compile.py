@@ -108,3 +108,60 @@ def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch):
     copies = re.findall(r"(%\S+) = " + re.escape(stacked)
                         + r"\{[^}]*\} copy\(", hlo)
     assert not copies, copies
+
+
+DSV2 = get_config("deepseek-v2-lite")
+
+
+def _described_fns(cfg, one_chip, slots, cache_len):
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: params_lib.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: model_lib.init_cache(cfg, slots, cache_len)))
+    return params, cache, engine._build_fns(cfg, cache_len)
+
+
+def test_dsv2_decode_step_writes_the_latent_cache_in_place(one_chip):
+    """deepseek-v2-lite's decode step at published widths, one chip's
+    expert share (32 slots x 2048, the cache donated): it stores each
+    token's latent and rotary key into the stacked cache in place, with
+    no copy of the whole cache, and hands back the routing counts."""
+    cfg = dataclasses.replace(DSV2, use_pallas=True)
+    slots, cache_len = 32, 2048
+    params, cache, (_, _, decode) = _described_fns(cfg, one_chip, slots,
+                                                   cache_len)
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    lowered = decode.lower(params, cache, toks)
+    counts = lowered.out_info[2]
+    assert counts.shape == (26, 16) and counts.dtype == jnp.int32
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    weight_bytes = sum(a.size * a.dtype.itemsize
+                       for a in jax.tree.leaves(params))
+    kv_bytes = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(cache))
+    assert 9.8e9 < weight_bytes < 9.9e9           # 4.91 B, bf16
+    assert 2.03e9 < kv_bytes < 2.05e9             # 27 x 576 x 2 B x 64k
+    assert mem.alias_size_in_bytes >= kv_bytes - 1e6
+    assert mem.temp_size_in_bytes < 0.1e9
+    hlo = compiled.as_text()
+    for name in ("ckv", "kr"):
+        stacked = "bf16[%s]" % ",".join(
+            map(str, cache["layers"][0][name].shape))
+        copies = re.findall(r"(%\S+) = " + re.escape(stacked)
+                            + r"\{[^}]*\} copy\(", hlo)
+        assert not copies, (name, copies)
+    assert "bf16[26,32,2048,512]" in hlo
+
+
+def test_dsv2_prefill_compiles_for_v5e(one_chip):
+    """A 1024-token prefill, the longest prompt of the cell's grid."""
+    cfg = dataclasses.replace(DSV2, use_pallas=True)
+    params, _, (prefill, _, _) = _described_fns(cfg, one_chip, 1, 2048)
+    toks = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
+    mem = prefill.lower(params, toks).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9
