@@ -87,9 +87,8 @@ def kernels_in_programs(engine, prompt_len: int) -> None:
     toks = jax.device_put(np.zeros((1, prompt_len), np.int32),
                           engine.device)
     prefill = engine.prefill_fn.lower(engine.params, toks).as_text()
-    slots = jax.device_put(engine.next_tokens, engine.device)
     decode = engine.decode_fn.lower(engine.params, engine.cache,
-                                    slots).as_text()
+                                    engine.tokens).as_text()
     for name, text in (("prefill", prefill), ("decode", decode)):
         n = text.count("tpu_custom_call")
         check(n > 0, f"{name}: no tpu_custom_call in the lowered program")

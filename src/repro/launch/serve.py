@@ -79,7 +79,7 @@ from repro.core.predictor import quick_bucket_predictor
 from repro.core.profiles import V100_LLAMA2_7B, tpu_v5e_profile
 from repro.core.workload import generate, to_requests
 from repro.models import params as params_lib
-from repro.serving.engine import LLMInstance
+from repro.serving.engine import LLMInstance, next_ids, place_first
 from repro.serving.gateway import (EngineClusterAdapter, Gateway,
                                    GatewayConfig, MicroBatchPredictor)
 from repro.serving.metrics import format_snapshot
@@ -145,8 +145,9 @@ def build_engine_fleet(arch: str, n_instances: int, *,
 
 def warm_fleet(engines, prompt_lengths) -> None:
     """Run each replica's prefill at every prompt length, its slot
-    insert and its decode step once, compiling in parallel threads, so
-    nothing compiles while serving.  Slots stay empty."""
+    insert, first-token placement, decode step and next-id argmax once,
+    compiling in parallel threads, so nothing compiles while serving.
+    Slots stay empty."""
     lengths = sorted(set(int(n) for n in prompt_lengths))
 
     def prefill(job):
@@ -155,11 +156,12 @@ def warm_fleet(engines, prompt_lengths) -> None:
         return jax.block_until_ready(e.prefill_fn(e.params, toks))
 
     def insert_and_decode(e):
-        _, small = prefill((e, lengths[0]))
+        logits, small = prefill((e, lengths[0]))
         e.cache = e.insert_fn(e.cache, small, 0)
-        toks = jax.device_put(e.next_tokens, e.device)
-        e.cache = e.decode_fn(e.params, e.cache, toks)[1]
-        jax.block_until_ready(e.cache)
+        e.tokens = place_first(e.tokens, logits, 0)
+        logits, e.cache = e.decode_fn(e.params, e.cache, e.tokens)[:2]
+        e.tokens = next_ids(logits)
+        jax.block_until_ready((e.cache, e.tokens))
 
     with ThreadPoolExecutor(os.cpu_count()) as ex:
         list(ex.map(prefill, [(e, n) for e in engines for n in lengths]))

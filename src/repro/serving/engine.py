@@ -36,6 +36,21 @@ from repro.serving.request import Phase, Request
 from repro.serving.scheduler import InstanceScheduler
 
 
+def _greedy(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _place(ids, logits, slot):
+    return ids.at[slot].set(_greedy(logits[0]))
+
+
+# the next decode step's input ids stay on the device: a decode step's
+# are the argmax of its logits, and a prefill's first token is put at
+# its slot; neither is read by the host before the next step is queued
+next_ids = jax.jit(_greedy)
+place_first = jax.jit(_place)
+
+
 @functools.lru_cache(maxsize=32)
 def _build_fns(cfg: ModelConfig, cache_len: int):
     """The jitted prefill, slot insert and decode step, named so that
@@ -86,7 +101,10 @@ class LLMInstance:
             self.cache = jax.device_put(
                 model_lib.init_cache(cfg, n_slots, cache_len), device)
         self.slots: List[Optional[Request]] = [None] * n_slots
-        self.next_tokens = np.zeros((n_slots,), np.int32)
+        # int32 [n_slots] on the device: the input ids of the next decode
+        # step (free rows hold whatever the last step made of them)
+        self.tokens = jax.device_put(np.zeros((n_slots,), np.int32),
+                                     device)
         self.queue: deque = deque()
         self.clock = 0.0
         self.completed: List[Request] = []
@@ -111,11 +129,36 @@ class LLMInstance:
         # index so lanes line up with the gateway's routing ids.
         self.trace = _trace.NULL
         self.trace_instance = instance_id
+        # decode steps dispatched, and those of them dispatched while the
+        # previous step's ids were not yet on the host
+        self.decode_steps = self.pipelined_steps = 0
         # running sums of what the decode steps routed (MoE configs):
         # steps, (token, expert) pairs that reached a held expert, and
         # held experts that at least one pair reached, summed over MoE
-        # layers; every row of a step counts, free slots too
-        self.moe_steps = self.moe_held_pairs = self.moe_experts_hit = 0
+        # layers; every row of a step counts, free slots too.  A step's
+        # counts reach the host with the next step's fetch; reading the
+        # sums drains those still on the device
+        self.moe_steps = self._moe_pairs = self._moe_hit = 0
+        self._moe_pending: list = []
+
+    def _add_moe(self, counts):
+        for c in counts:
+            self._moe_pairs += int(c.sum())
+            self._moe_hit += int(np.count_nonzero(c))
+
+    def _drain_moe(self):
+        self._add_moe(jax.device_get(self._moe_pending))
+        self._moe_pending = []
+
+    @property
+    def moe_held_pairs(self) -> int:
+        self._drain_moe()
+        return self._moe_pairs
+
+    @property
+    def moe_experts_hit(self) -> int:
+        self._drain_moe()
+        return self._moe_hit
 
     # -- router-visible state ----------------------------------------------
     @property
@@ -220,9 +263,8 @@ class LLMInstance:
                                   self.device)
             logits, small = self.prefill_fn(self.params, toks)
             self.cache = self.insert_fn(self.cache, small, slot)
+            self.tokens = place_first(self.tokens, logits, slot)
             self.slots[slot] = req
-            with _trace.span("engine.fetch"):
-                self.next_tokens[slot] = int(jnp.argmax(logits[0]))
         req.phase = Phase.DECODE
         req.prefilled = req.prompt_tokens
         req.prefill_done = self.clock
@@ -233,21 +275,24 @@ class LLMInstance:
         if not active:
             return done
         with _trace.span("engine.decode"):
-            toks = jax.device_put(self.next_tokens, self.device)
-            if self.cfg.moe is None:
-                logits, self.cache = self.decode_fn(self.params, self.cache,
-                                                    toks)
-                with _trace.span("engine.fetch"):
-                    nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            else:
-                logits, self.cache, counts = self.decode_fn(
-                    self.params, self.cache, toks)
-                with _trace.span("engine.fetch"):    # one sync for both
-                    nxt, counts = jax.device_get(
-                        (jnp.argmax(logits, axis=-1), counts))
-                self.moe_steps += 1
-                self.moe_held_pairs += int(counts.sum())
-                self.moe_experts_hit += int(np.count_nonzero(counts))
+            # dispatch step n, then wait for its inputs (step n-1's
+            # outputs, and a first token placed this iteration) while it
+            # runs: the chip always has a step queued while the host waits
+            toks = self.tokens
+            out = self.decode_fn(self.params, self.cache, toks)
+            logits, self.cache = out[:2]
+            self.tokens = next_ids(logits)
+            self.decode_steps += 1
+            # a row that has decoded takes the previous step's output,
+            # which is still on the device
+            self.pipelined_steps += any(self.slots[i].decoded
+                                        for i in active)
+            counts = self._moe_pending        # earlier steps' routing counts
+            self._moe_pending = list(out[2:])  # this step's (MoE configs)
+            self.moe_steps += len(out) - 2
+            with _trace.span("engine.fetch"):    # one sync for both
+                ids, counts = jax.device_get((toks, counts))
+            self._add_moe(counts)
             tr = self.trace
             for i in active:
                 r = self.slots[i]
@@ -258,8 +303,7 @@ class LLMInstance:
                         tr.emit(self.clock, _trace.EV_FIRST_TOKEN, r.rid,
                                 self.trace_instance, r.tenant)
                 r.token_times.append(self.clock)
-                r.output_tokens.append(int(self.next_tokens[i]))
-                self.next_tokens[i] = nxt[i]
+                r.output_tokens.append(int(ids[i]))
                 if r.decoded >= r.decode_tokens:
                     r.phase = Phase.DONE
                     r.finished = self.clock

@@ -79,7 +79,8 @@ def test_served_path_writes_every_span(traced):
     # the names the benchmark's window is taken from stay the harness's
     assert not any(e[1] in ("advance", "route") for e in events)
     by = lambda name: [e for e in ours if e[1] == name]   # noqa: E731
-    for inner, outer in (("engine.fetch", "engine.step"),
+    for inner, outer in (("engine.fetch", "engine.decode"),
+                         ("engine.fetch", "engine.step"),
                          ("engine.prefill", "engine.step"),
                          ("engine.decode", "engine.step"),
                          ("engine.step", "cluster.advance"),
@@ -87,10 +88,12 @@ def test_served_path_writes_every_span(traced):
                          ("gateway.route", "gateway.tick")):
         for e in by(inner):
             assert any(_inside(e, o) for o in by(outer)), (inner, outer)
-    # one prefill per request, and a fetch in each prefill and decode
+    # one prefill per request, which fetches nothing: the one fetch of
+    # each decode pass brings the first tokens with the carried ids
     assert len(by("engine.prefill")) == len(reqs)
-    assert len(by("engine.fetch")) == \
-        len(by("engine.prefill")) + len(by("engine.decode"))
+    assert len(by("engine.fetch")) == len(by("engine.decode"))
+    for e in by("engine.fetch"):
+        assert not any(_inside(e, p) for p in by("engine.prefill"))
 
 
 def test_spans_change_no_served_id(traced):
