@@ -1,10 +1,9 @@
 """Unified simulator-backend registry.
 
-Backend selection used to be a string scattered across
-``Cluster(backend=)``, ``GatewayConfig.backend``,
-``BatchedRLConfig.sim_backend`` and ``FidelityConfig.backends``; each
-call site hard-coded its own dispatch, so adding a backend meant
-touching all of them.  This module is now the single resolution point:
+Backend selection is a string at ``Cluster(backend=)``,
+``GatewayConfig.backend``, ``BatchedRLConfig.backend`` and
+``FidelityConfig.backends``; this module is the single point that
+resolves it:
 
     from repro.core.backends import make_backend
     cluster = make_backend("jax").make_cluster(profile, 4)

@@ -28,7 +28,6 @@ runner trains >3x faster on 2 CPU cores (benchmarks/bench_batched_rl).
 """
 from __future__ import annotations
 
-import warnings
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence
@@ -82,18 +81,6 @@ class BatchedRLConfig:
     # spans on the numpy fast path and sends only long drain spans to
     # the jitted kernel)
     pool_kwargs: Optional[Dict] = None
-    # DEPRECATED alias for ``backend`` (pre-registry spelling); when
-    # set it wins, with a DeprecationWarning.
-    sim_backend: Optional[str] = None
-
-    def __post_init__(self):
-        if self.sim_backend is not None:
-            warnings.warn(
-                "BatchedRLConfig.sim_backend is deprecated; use "
-                "BatchedRLConfig(backend=...) — backends now resolve "
-                "through the core.backends registry",
-                DeprecationWarning, stacklevel=3)
-            self.backend = self.sim_backend
 
 
 class _Slot:
@@ -477,19 +464,12 @@ def evaluate_scenarios(cfg: rl.RouterConfig, agent,
                        scenarios: Sequence[Scenario],
                        predict_decode: Optional[Callable] = None,
                        m_max: Optional[int] = None,
-                       backend: str = "py",
-                       sim_backend: Optional[str] = None) -> List[Dict]:
+                       backend: str = "py") -> List[Dict]:
     """Greedy (epsilon=0, no learning) batched evaluation; one stats dict
     per scenario, same fields as `rl_router.evaluate`.  With a single
     homogeneous scenario of width cfg.n_instances this reproduces the
     sequential evaluate decision for decision (on any registry
-    backend).  ``sim_backend=`` is the deprecated alias of
-    ``backend=``."""
-    if sim_backend is not None:
-        warnings.warn(
-            "evaluate_scenarios(sim_backend=...) is deprecated; use "
-            "backend=...", DeprecationWarning, stacklevel=2)
-        backend = sim_backend
+    backend)."""
     m_max = m_max or max([cfg.n_instances] + [s.m for s in scenarios])
     pool = None
     if backend != "py":

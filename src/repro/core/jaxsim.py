@@ -390,6 +390,7 @@ class JaxSimPool(VecSimPool):
         if not self._jax_eligible(target):
             self.n_numpy_calls += 1
             return super()._run_rounds(target, done)
+        self._park_failed(target)
         behind = self.clock < target
         if not behind.any():
             return

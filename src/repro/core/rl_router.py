@@ -19,7 +19,6 @@ evidently intended penalty  + w_k * h.
 """
 from __future__ import annotations
 
-import warnings
 from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Sequence
@@ -276,14 +275,11 @@ class RoutingEnv:
     simulator (`core.vecsim`), ``"jax"`` on the device-resident jitted
     round loop (`core.jaxsim`).  Passing a shared ``pool`` +
     ``pool_ep`` instead packs this episode into a multi-episode pool
-    so the batched trainer advances all its episodes in fused rounds.
-    ``sim_backend=`` is the deprecated pre-registry alias of
-    ``backend=``."""
+    so the batched trainer advances all its episodes in fused rounds."""
 
     def __init__(self, cfg: RouterConfig, profile,
                  predict_decode: Optional[Callable] = None,
-                 backend: Optional[str] = None,
-                 sim_backend: Optional[str] = None,
+                 backend: str = "py",
                  pool=None, pool_ep: int = 0):
         self.cfg = cfg
         if isinstance(profile, HardwareProfile):
@@ -292,15 +288,7 @@ class RoutingEnv:
             self.profiles = tuple(profile)
         self.profile = self.profiles[0]     # router-level reference
         self.m = len(self.profiles)
-        if sim_backend is not None:
-            warnings.warn(
-                "RoutingEnv(sim_backend=...) is deprecated; use "
-                "backend=... — backends now resolve through the "
-                "core.backends registry", DeprecationWarning,
-                stacklevel=2)
-            backend = backend or sim_backend
-        backend = backend or "py"
-        self.sim_backend = "vec" if pool is not None else backend
+        self.backend = "vec" if pool is not None else backend
         self._pool = pool
         self._pool_ep = pool_ep
         # d-hat: estimated decode tokens for a request (predictor hook;
@@ -322,7 +310,7 @@ class RoutingEnv:
             self.cluster = Cluster(
                 self.profiles, self.m, c.scheduler, c.dt,
                 c.chunked_prefill, c.n_slots,
-                backend=self.sim_backend,
+                backend=self.backend,
                 prefix_cache_tokens=c.prefix_cache_tokens,
                 prefix_block=c.prefix_block)
         self._vec = getattr(self.cluster, "is_vec", False)

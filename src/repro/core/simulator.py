@@ -283,9 +283,6 @@ class SimInstance:
             self.trace.emit(self.clock, _trace.EV_RECOVER, -1,
                             self.instance_id)
 
-    def restore(self):
-        self.failed = False
-
     def steal(self, req: Request) -> bool:
         """Withdraw a routed request (hedged re-dispatch): remove it
         from this instance's queue or residents, reset its progress and

@@ -34,8 +34,8 @@ backlog accumulators (reward-only, never decisions).
 
 Entry points:
   * ``Cluster(..., backend="vec")`` returns a :class:`VecCluster`
-    (drop-in for the Cluster protocol: run_heuristic, the gateway, the
-    RL env, and ManagedCluster all work unchanged);
+    (drop-in for the Cluster protocol: run_heuristic, the gateway and
+    the RL env all work unchanged);
   * ``VecSimPool(n_episodes)`` + ``VecCluster(..., pool=, ep=)`` packs
     many episodes into ONE set of arrays so the batched RL trainer
     steps all of them per round (``pool.advance([eps...])``) -- cost
@@ -384,15 +384,11 @@ class VecSimPool:
         self._config_lane(lane, ep, local, prof, scheduler,
                           chunked_prefill, n_slots,
                           prefix_cache_tokens, prefix_block)
-        self.clock[lane] = self.ep_t[ep]
+        # ep_t may be BEHIND the episode's cached min-clock bound
+        # (existing lanes overshoot ticks)
+        self.set_clock(lane, self.ep_t[ep])
         self.ep_lanes[ep] = np.append(self.ep_lanes[ep], lane)
         self._lanes_ver += 1
-        # the new lane's clock sits at ep_t, which may be BEHIND the
-        # episode's cached min-clock bound (existing lanes overshoot
-        # ticks); without lowering it the advance() fast path would
-        # skip stepping the new lane entirely
-        self._ep_min_clock[ep] = min(self._ep_min_clock[ep],
-                                     self.clock[lane])
         return lane
 
     # -- request intake --------------------------------------------------
@@ -602,6 +598,7 @@ class VecSimPool:
         (core.jaxsim) overrides ONLY this method to run the same
         rounds as one jitted ``while_loop``; any future backend (e.g.
         an accelerator-resident port) should do the same."""
+        self._park_failed(target)
         behind = self.clock < target
         if behind.any():
             runnable = ((self.res_cnt > 0) | (self.qcnt > 0)) \
@@ -622,6 +619,15 @@ class VecSimPool:
                     active &= ~dry
         if self._trbuf:
             self.drain_trace()
+
+    def _park_failed(self, target: np.ndarray):
+        """A failed lane idles at the advance target, also where the
+        iteration it died in had carried its clock past it
+        (SimInstance.run_until parity).  Lanes outside the advance set
+        carry a -inf target and are left alone."""
+        down = self.failed & (target > -np.inf)
+        if down.any():
+            self.clock[down] = target[down]
 
     def _advance_rounds(self, lanes_all: np.ndarray,
                         done: Dict[int, List[int]]):
@@ -1111,6 +1117,9 @@ class VecSimPool:
                    for c in self.resident_cols(lane)]
         orphans += [int(x) for x in self.queue_gids(lane)]
         self.failed[lane] = True
+        # the next advance must run the round loop, which parks this
+        # lane's clock at its target (_park_failed)
+        self._ep_min_clock[self.lane_ep[lane]] = -np.inf
         self.q_gid[lane] = -1
         self._tot_q -= int(self.qcnt[lane])
         self.qcnt[lane] = 0
@@ -1157,6 +1166,15 @@ class VecSimPool:
             self._trbuf.append(("recover", float(self.clock[lane]),
                                 lane))
             self.drain_trace()
+
+    def set_clock(self, lane: int, t: float):
+        """Write a lane's clock, also backwards (a restore that puts a
+        node back at the cluster time), lowering the episode's cached
+        min-clock bound with it: a stale bound would let the advance()
+        fast path skip the lane."""
+        self.clock[lane] = t
+        ep = self.lane_ep[lane]
+        self._ep_min_clock[ep] = min(self._ep_min_clock[ep], t)
 
     def steal_request(self, gid: int) -> bool:
         """Withdraw a routed request for hedged re-dispatch
@@ -1368,7 +1386,7 @@ class VecInstanceView:
 
     @clock.setter
     def clock(self, t: float):
-        self.pool.clock[self.lane] = t
+        self.pool.set_clock(self.lane, t)
 
     @property
     def spikes(self) -> List[float]:
@@ -1448,9 +1466,6 @@ class VecInstanceView:
 
     def recover(self):
         self.pool.recover_lane(self.lane)
-
-    def restore(self):
-        self.pool.failed[self.lane] = False
 
 
 class VecCluster:

@@ -1,26 +1,28 @@
-"""Serving launcher: intelligent-router cluster over real JAX engine
-replicas or the calibrated simulator.
+"""Serving launcher: the intelligent-router gateway over the calibrated
+simulator (--backend py|vec|jax) or real JAX engine replicas
+(--backend engine).  Every run goes through ``serving.gateway.Gateway``.
 
-  # online gateway: open-loop multi-tenant stream, pluggable policy,
-  # learned length predictor in the loop, rolling SLO metrics
-  PYTHONPATH=src python -m repro.launch.serve --mode gateway \
+  # open-loop multi-tenant stream, pluggable policy, learned length
+  # predictor in the loop, rolling SLO metrics; --policy rl trains a
+  # quick agent on the simulator unless --checkpoint is given
+  PYTHONPATH=src python -m repro.launch.serve \
       --policy rl|mixing|jsq|rr --pattern bursty --queue-cap 64
 
   # same, with lifecycle tracing + metrics export (serving.obs):
   # trace.json opens in Perfetto, metrics.json/.prom is the scrape
-  PYTHONPATH=src python -m repro.launch.serve --mode gateway \
+  PYTHONPATH=src python -m repro.launch.serve \
       --trace trace.json --metrics-out metrics.json
 
-  # gateway over real engines: qwen3-0.6b at published widths, one
-  # replica per accelerator (TPU); --reduced runs the tiny preset on CPU
-  PYTHONPATH=src python -m repro.launch.serve --mode gateway \
+  # real engines: qwen3-0.6b at published widths, one replica per
+  # accelerator (TPU); --reduced runs the tiny preset on CPU
+  PYTHONPATH=src python -m repro.launch.serve \
       --backend engine --instances 1 --requests 12
   JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
-      --mode gateway --backend engine --reduced --requests 12
+      --backend engine --reduced --requests 12
 
   # chaos drill: seeded crash/straggler schedule with gateway failover
   # (bounded-retry re-admission, circuit breaker, hedged re-dispatch)
-  PYTHONPATH=src python -m repro.launch.serve --mode gateway \
+  PYTHONPATH=src python -m repro.launch.serve \
       --chaos-seed 7 --chaos-crashes 1 --chaos-stragglers 1 \
       --failover --hedge-after 4.0
 
@@ -28,7 +30,7 @@ replicas or the calibrated simulator.
   # live stream (training.online) with the r_mixing safe-fallback
   # guardrail; --drift serves the nonstationary mix-flip scenario where
   # a frozen policy degrades.  --checkpoint warm-starts the learner.
-  PYTHONPATH=src python -m repro.launch.serve --mode gateway \
+  PYTHONPATH=src python -m repro.launch.serve \
       --online --guardrail 0.12 --drift --checkpoint ckpt_dir
 
   # calibrate a HardwareProfile from the real engine (core.calibrate):
@@ -38,15 +40,7 @@ replicas or the calibrated simulator.
       --arch qwen3-0.6b --profile-json profile.json --min-r2 0.95
 
   # serve on a previously calibrated profile instead of the V100 default
-  PYTHONPATH=src python -m repro.launch.serve --mode gateway \
-      --profile-json profile.json
-
-  # closed-loop simulator episode (legacy path)
-  PYTHONPATH=src python -m repro.launch.serve --mode sim --requests 400
-
-  # real tiny engines, impact-heuristic routing (legacy path)
-  PYTHONPATH=src python -m repro.launch.serve --mode engine --reduced \
-      --requests 12
+  PYTHONPATH=src python -m repro.launch.serve --profile-json profile.json
 """
 from __future__ import annotations
 
@@ -72,9 +66,8 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import calibrate as cal
-from repro.core import impact, rl_router as rl
+from repro.core import rl_router as rl
 from repro.core import workload as wl
-from repro.core.cluster_manager import ManagedCluster, ManagedClusterConfig
 from repro.core.predictor import quick_bucket_predictor
 from repro.core.profiles import V100_LLAMA2_7B, tpu_v5e_profile
 from repro.core.workload import generate, to_requests
@@ -85,7 +78,7 @@ from repro.serving.gateway import (EngineClusterAdapter, Gateway,
 from repro.serving.metrics import format_snapshot
 from repro.serving.policies import (RLPolicy, make_gateway_policy,
                                     restore_rl_policy)
-from repro.serving.request import Request, summarize
+from repro.serving.request import Request
 from repro.serving.scheduler import get_scheduler
 
 
@@ -197,12 +190,11 @@ def _router_cfg(args) -> rl.RouterConfig:
                                          else 0.0),
                            include_cache_features=bool(
                                args.prefix_cache),
-                           include_health_features=bool(
-                               getattr(args, "online", False)))
+                           include_health_features=args.online)
 
 
-def _train_quick_agent(args, cfg: rl.RouterConfig, profile=None):
-    out = rl.train(cfg, profile or V100_LLAMA2_7B,
+def _train_quick_agent(args, cfg: rl.RouterConfig, profile):
+    out = rl.train(cfg, profile,
                    lambda ep: to_requests(generate(args.requests, seed=ep),
                                           rate=args.rate, seed=ep + 50),
                    n_episodes=args.train_episodes)
@@ -243,20 +235,6 @@ def run_calibrate(args) -> int:
     return 1 if failures else 0
 
 
-def serve_sim(args):
-    cfg = _router_cfg(args)
-    base = _base_profile(args)
-    agent = _train_quick_agent(args, cfg, base)
-    mgr = ManagedCluster(ManagedClusterConfig(n_instances=args.instances),
-                         cfg, base, agent)
-    reqs = to_requests(generate(args.requests, seed=991), rate=args.rate,
-                       seed=992)
-    stats = mgr.serve(reqs)
-    print(f"served n={stats['n']} e2e={stats['e2e_mean']:.2f}s "
-          f"ttft={stats['ttft_mean']:.2f}s "
-          f"preemptions={stats['preemptions']}")
-
-
 def _engines(args) -> list:
     """The CLI's engine fleet: published widths on the accelerator, or
     with --reduced the tiny CPU preset (4 slots, a cache that fits the
@@ -290,32 +268,14 @@ def _chaos_schedule(args):
         n_bursts=args.chaos_bursts)
 
 
-def _resolve_backend(args) -> str:
-    """Map the CLI flags onto one core.backends registry name.
-    ``--backend`` names the backend directly ('sim' is the legacy
-    alias for the default stepper); ``--sim-backend`` is the
-    deprecated pre-registry spelling and wins when set."""
-    import warnings
-    backend = "py" if args.backend == "sim" else args.backend
-    if args.sim_backend is not None:
-        warnings.warn(
-            "--sim-backend is deprecated; use --backend py|vec|jax "
-            "(backends now resolve through the core.backends registry)",
-            DeprecationWarning, stacklevel=2)
-        if args.backend in ("sim", args.sim_backend):
-            backend = args.sim_backend
-    return backend
-
-
-def serve_gateway(args):
+def serve(args):
     """Online gateway over the simulator (default) or real engines."""
     cfg = _router_cfg(args)
     chaos = _chaos_schedule(args)
-    sim_backend = _resolve_backend(args)
     gcfg = GatewayConfig(queue_cap=args.queue_cap, on_full=args.on_full,
                          scheduler=args.scheduler,
                          chunked_prefill=args.chunked_prefill,
-                         backend=sim_backend,
+                         backend=args.backend,
                          default_deadline_s=args.deadline,
                          prefix_cache_tokens=args.prefix_cache,
                          prefix_block=args.prefix_block,
@@ -331,7 +291,7 @@ def serve_gateway(args):
     if args.backend == "engine":
         if args.online:
             raise SystemExit("--online needs a simulator backend "
-                             "(py/vec): the engine adapter fires no "
+                             "(py/vec/jax): the engine adapter fires no "
                              "decode events for the backlog reward)")
         if args.policy == "rl" and not args.checkpoint:
             raise SystemExit("--backend engine --policy rl needs "
@@ -440,38 +400,13 @@ def serve_gateway(args):
             print(f"wrote {args.metrics_out} ({len(reg)} metrics)")
 
 
-def serve_engine(args):
-    insts = _engines(args)
-    prof = insts[0].profile
-    reqs = draw_engine_requests(args.requests, insts[0].cfg.vocab_size,
-                                args.seed)
-    for r in reqs:   # impact-heuristic routing (Eq. 1-2)
-        scores = impact.mixing_per_instance(
-            prof, r.prompt_tokens, r.decode_tokens,
-            [i.resident_tokens() for i in insts])
-        insts[int(np.argmax(scores))].submit(r)
-        for inst in insts:
-            inst.step()
-    while sum(len(i.completed) for i in insts) < len(reqs):
-        if not any(inst.queue or any(inst.slots) for inst in insts):
-            break
-        for inst in insts:
-            inst.step()
-    print(summarize(reqs))
-
-
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=("sim", "engine", "gateway"),
-                    default="sim")
-    ap.add_argument("--backend",
-                    choices=("sim", "py", "vec", "jax", "engine"),
-                    default="sim",
-                    help="gateway cluster backend: any name from the "
-                    "core.backends registry ('py'/'vec'/'jax' pick the "
-                    "simulator stepper, 'engine' runs real engine "
-                    "replicas); 'sim' is the legacy alias for the "
-                    "default simulator stepper")
+    ap.add_argument("--backend", choices=("py", "vec", "jax", "engine"),
+                    default="py",
+                    help="cluster behind the gateway: a simulator "
+                    "stepper from the core.backends registry "
+                    "(py/vec/jax) or real engine replicas (engine)")
     ap.add_argument("--reduced", action="store_true",
                     help="engine replicas at the tiny CPU preset "
                     "(ModelConfig.reduced) instead of the published "
@@ -481,16 +416,11 @@ def main():
     ap.add_argument("--policy", default="mixing",
                     choices=("rl", "mixing", "mixing+cache", "jsq",
                              "rr", "sticky"),
-                    help="gateway routing policy")
+                    help="routing policy")
     ap.add_argument("--pattern", default="bursty",
                     choices=("poisson", "bursty", "diurnal"))
     ap.add_argument("--queue-cap", type=int, default=0,
                     help="admission queue bound (0 = unbounded)")
-    ap.add_argument("--sim-backend", choices=("py", "vec", "jax"),
-                    default=None,
-                    help="DEPRECATED alias: use --backend py|vec|jax "
-                    "(backends now resolve through the core.backends "
-                    "registry)")
     ap.add_argument("--deadline", type=float, default=None,
                     help="client timeout in seconds (deferred requests "
                     "past it are cancelled)")
@@ -503,7 +433,7 @@ def main():
     ap.add_argument("--prefix-block", type=int, default=32,
                     help="prefix-cache hash-block size in tokens")
     ap.add_argument("--sessions", action="store_true",
-                    help="gateway: multi-turn conversation workload "
+                    help="multi-turn conversation workload "
                     "(follow-up prompts extend prior turns; tenants "
                     "share system prompts) instead of independent "
                     "queries")
@@ -511,7 +441,7 @@ def main():
                     help="router checkpoint dir for --policy rl (and "
                     "the warm-start source for --online)")
     ap.add_argument("--online", action="store_true",
-                    help="gateway: continual learning on the live "
+                    help="continual learning on the live "
                     "stream (training.online) -- the router trains on "
                     "its own transitions between arrival windows and "
                     "hot-swaps refreshed weights without pausing "
@@ -527,7 +457,7 @@ def main():
                     help="--online guided exploration rate (softmax "
                     "over the r_mixing guidance bonus)")
     ap.add_argument("--drift", action="store_true",
-                    help="gateway: serve the nonstationary drift "
+                    help="serve the nonstationary drift "
                     "scenario (mid-stream workload-mix flip + tenant "
                     "churn + straggler/crash chaos) instead of the "
                     "stationary tenant mix")
@@ -536,7 +466,7 @@ def main():
                     "learner state (Q + target + optimizer + replay) "
                     "here for exact mid-stream resume")
     ap.add_argument("--chaos-seed", type=int, default=None,
-                    help="gateway: inject a seeded FaultSchedule "
+                    help="inject a seeded FaultSchedule "
                     "(serving.chaos) of crashes / stragglers / tenant "
                     "bursts into the run")
     ap.add_argument("--chaos-crashes", type=int, default=1,
@@ -557,13 +487,13 @@ def main():
                     "request still tokenless after this long and "
                     "re-route it (None = off)")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="gateway: record request lifecycle spans and "
+                    help="record request lifecycle spans and "
                     "write a Chrome trace-event JSON (load in Perfetto "
                     "/ chrome://tracing)")
     ap.add_argument("--trace-sample", type=float, default=1.0,
                     help="head-sampling fraction of requests traced")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
-                    help="gateway: write the metrics registry (SLO "
+                    help="write the metrics registry (SLO "
                     "snapshot + decision attribution + RL telemetry) "
                     "as JSON, or Prometheus text if PATH ends in "
                     ".prom")
@@ -590,12 +520,7 @@ def main():
     use_compile_cache()
     if args.calibrate:
         sys.exit(run_calibrate(args))
-    if args.mode == "sim":
-        serve_sim(args)
-    elif args.mode == "gateway":
-        serve_gateway(args)
-    else:
-        serve_engine(args)
+    serve(args)
 
 
 if __name__ == "__main__":

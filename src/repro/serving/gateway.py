@@ -1,7 +1,7 @@
 """Online serving gateway: streaming request router with pluggable
 policies, an in-loop length predictor, and rolling SLO metrics.
 
-The closed-loop entry point (``ManagedCluster.serve(reqs)``) consumes a
+The closed-loop entry point (``rl_router.evaluate``) consumes a
 pre-materialized request list; a production router is an *open-loop*
 service -- requests arrive continuously whether or not the cluster is
 keeping up, and each must be routed on arrival.  The gateway provides
@@ -26,7 +26,7 @@ that loop as a first-class subsystem:
     TTFT/TBT/E2E, per-tenant breakdowns, SLO attainment, shed counters.
 
 With an unbounded queue, the oracle length service, and the RL policy,
-the gateway reproduces ``ManagedCluster.serve`` decision for decision
+the gateway reproduces ``rl_router.evaluate`` decision for decision
 (tests/test_gateway.py) -- the closed-loop path is a special case of
 this subsystem.
 
@@ -298,8 +298,9 @@ class GatewayConfig:
     metrics_window: float = 300.0
     quantiles: Tuple[float, ...] = (0.5, 0.95, 0.99)
     slo: SLO = field(default_factory=SLO)
-    # simulator backend for the built-in cluster: "py" (SimInstance
-    # reference stepper) or "vec" (core.vecsim structure-of-arrays)
+    # simulator backend for the built-in cluster, from the
+    # core.backends registry: "py" (SimInstance reference stepper),
+    # "vec" (core.vecsim structure-of-arrays) or "jax" (core.jaxsim)
     backend: str = "py"
     # per-instance prefix/KV cache model (core.prefix_cache); 0 = off
     prefix_cache_tokens: int = 0

@@ -4,7 +4,7 @@ One ``RoutingPolicy`` protocol unifies the repo's previously scattered
 routing paths -- the heuristic baselines in ``core.policies`` (driven
 through ``simulator.run_heuristic``), the r_mixing heuristic embedded in
 ``RoutingEnv.guidance_bonus``, and the trained RL agent driven by
-``ManagedCluster.serve`` -- so any of them is a one-line swap in the
+``rl_router.evaluate`` -- so any of them is a one-line swap in the
 gateway / ``launch.serve``:
 
     route(cluster, req, d_hat) -> Optional[int]
@@ -187,10 +187,10 @@ class MixingImpactPolicy:
 
 class RLPolicy:
     """A trained DQN router behind the gateway.  Decision math is
-    identical to ``ManagedCluster.serve`` (greedy masked Q + guidance
+    identical to ``rl_router.evaluate`` (greedy masked Q + guidance
     prior, decomposed-arch aware), so a gateway with the oracle
     predictor reproduces the closed-loop path decision for decision
-    (tests/test_gateway.py::test_policy_parity_with_managed_cluster)."""
+    (tests/test_gateway.py::test_policy_parity_with_evaluate)."""
     name = "rl"
 
     def __init__(self, agent, router_cfg: rl.RouterConfig):
@@ -233,7 +233,7 @@ class RLPolicy:
                 s, mask, epsilon=0.0, prior=prior,
                 q_squash=cfg.q_squash if w_sel else 0.0))
         # fixed-m MLP cannot score a resized cluster: fall back to the
-        # guidance heuristic (same degradation as ManagedCluster)
+        # guidance heuristic
         bonus[~mask] = -np.inf
         return int(np.argmax(bonus))
 
@@ -298,7 +298,8 @@ def restore_rl_policy(router_cfg: rl.RouterConfig, checkpoint_dir: str,
                       m: Optional[int] = None) -> RLPolicy:
     """Rebuild the agent for an m-wide action space and restore its
     weights from a ``training.checkpoint`` directory (the artifact
-    ``ManagedCluster.save_router`` / the trainers write)."""
+    ``CheckpointManager.save(step, agent.state_dict())`` and the
+    trainers write)."""
     from repro.training.checkpoint import CheckpointManager
     agent = rl.make_agent(router_cfg, m=m)
     out = CheckpointManager(checkpoint_dir).restore(agent.state_dict())

@@ -1,7 +1,8 @@
 """Optional-hypothesis shim.
 
 Tier-1 must collect and pass on a bare interpreter (no ``hypothesis``):
-property tests import ``given``/``settings``/``st`` from here.  When
+property tests import ``given``/``settings``/``example``/``st`` from
+here.  When
 hypothesis is available this is a transparent re-export; when it is not,
 ``@given`` replaces the test with a zero-argument stub marked skip (the
 strategy-valued parameters would otherwise be collected as fixtures).
@@ -9,7 +10,8 @@ strategy-valued parameters would otherwise be collected as fixtures).
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import (example, given, settings,  # noqa: F401
+                            strategies as st)
     HAS_HYPOTHESIS = True
 except ImportError:
     HAS_HYPOTHESIS = False
@@ -30,6 +32,8 @@ except ImportError:
         def deco(fn):
             return fn
         return deco
+
+    example = settings
 
     def given(*_args, **_kwargs):
         def deco(fn):

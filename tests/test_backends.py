@@ -1,17 +1,12 @@
-"""core.backends registry resolution and the deprecation shims that
-keep the pre-registry kwargs (``sim_backend=`` spellings) working."""
-import warnings
-
+"""core.backends registry resolution."""
 import pytest
 
-from repro.core import batched_rl, rl_router as rl
 from repro.core.backends import (available_backends, make_backend,
                                  register_backend)
 from repro.core.jaxsim import JaxSimPool
 from repro.core.profiles import V100_LLAMA2_7B
 from repro.core.simulator import Cluster
 from repro.core.vecsim import VecCluster, VecSimPool
-from repro.core.workload import Scenario, generate, to_requests
 from repro.serving.gateway import Gateway, GatewayConfig
 from repro.serving.policies import make_gateway_policy
 
@@ -74,37 +69,3 @@ def test_gateway_backend_resolves_through_registry():
     assert isinstance(gw.cluster.pool, JaxSimPool)
 
 
-# -- deprecation shims -------------------------------------------------------
-
-def test_batched_config_sim_backend_shim():
-    with pytest.warns(DeprecationWarning, match="sim_backend is"):
-        bcfg = batched_rl.BatchedRLConfig(n_envs=2, sim_backend="vec")
-    assert bcfg.backend == "vec"
-    # the new spelling stays silent
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        bcfg = batched_rl.BatchedRLConfig(n_envs=2, backend="jax")
-    assert bcfg.backend == "jax"
-
-
-def test_routing_env_sim_backend_shim():
-    cfg = rl.RouterConfig(n_instances=2, seed=0)
-    with pytest.warns(DeprecationWarning, match="sim_backend"):
-        env = rl.RoutingEnv(cfg, PROF, sim_backend="vec")
-    assert env.sim_backend == "vec"
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        env = rl.RoutingEnv(cfg, PROF, backend="vec")
-    assert env.sim_backend == "vec"
-
-
-def test_evaluate_scenarios_sim_backend_shim():
-    cfg = rl.RouterConfig(variant="guided", n_instances=2,
-                          q_arch="decomposed", seed=0)
-    agent = rl.make_agent(cfg)
-    reqs = to_requests(generate(12, seed=1), rate=20.0, seed=2)
-    scn = Scenario.homogeneous(PROF, 2, reqs)
-    with pytest.warns(DeprecationWarning, match="sim_backend"):
-        out = batched_rl.evaluate_scenarios(cfg, agent, [scn],
-                                            sim_backend="vec")
-    assert out[0]["n"] == 12

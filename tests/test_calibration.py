@@ -138,7 +138,7 @@ def test_make_scenario_profiles_override():
 
 # -- fidelity harness --------------------------------------------------------
 
-def test_fidelity_sim_backends_match_bitwise():
+def test_fidelity_simulator_backends_match_bitwise():
     """The harness's vec-vs-py deltas must be exactly zero and the
     report must carry the full percentile/delta shape."""
     fcfg = fid.FidelityConfig(backends=("py", "vec"), n_requests=30)

@@ -6,7 +6,6 @@ import pytest
 from repro.core import batched_rl, predictor as pred_lib
 from repro.core import rl_router as rl
 from repro.core import workload as wl
-from repro.core.cluster_manager import ManagedCluster, ManagedClusterConfig
 from repro.core.dqn import DQNConfig, ReplayBuffer
 from repro.core.profiles import V100_LLAMA2_7B
 from repro.serving.gateway import (Gateway, GatewayConfig,
@@ -130,22 +129,22 @@ def test_unbounded_queue_never_sheds():
 
 # -- policy parity with the closed-loop path ---------------------------------
 
-def test_policy_parity_with_managed_cluster():
+def test_policy_parity_with_evaluate():
     """Gateway + RL policy + oracle length service + unbounded queue
-    must reproduce ManagedCluster.serve decision for decision."""
+    must reproduce rl_router.evaluate decision for decision."""
     cfg = rl.RouterConfig(variant="guided", n_instances=3,
                           q_arch="decomposed", seed=0)
     agent = rl.make_agent(cfg)
     reqs_a = wl.to_requests(wl.generate(120, seed=7), rate=20.0, seed=8)
     reqs_b = wl.to_requests(wl.generate(120, seed=7), rate=20.0, seed=8)
-    mc = ManagedCluster(ManagedClusterConfig(n_instances=3), cfg, PROF,
-                        agent)
-    seq = mc.serve(reqs_a)
+    seq = rl.evaluate(cfg, PROF, agent, reqs_a)
     gw = Gateway(GatewayConfig(), (PROF,) * 3, RLPolicy(agent, cfg),
                  length=OracleLength())
     bat = gw.run(reqs_b)
     assert seq["n"] == bat["n"] == 120
     for a, b in zip(reqs_a, reqs_b):
+        assert (a.arrival, a.prompt_tokens) == (b.arrival,
+                                                b.prompt_tokens)
         assert a.finished == pytest.approx(b.finished, abs=1e-9)
         assert a.instance == b.instance
         assert a.preemptions == b.preemptions

@@ -72,7 +72,7 @@ def test_jax_parity_property(seed, m, chunk, fail):
                 cluster.fail_instance(0)
                 failed = True
             if do_fail and cluster.t > 2.0 and not restored:
-                cluster.instances[0].restore()
+                cluster.instances[0].recover()
                 cluster.instances[0].clock = cluster.t
                 restored = True
             alive = cluster.alive()

@@ -388,7 +388,7 @@ def test_guardrail_stays_quiet_for_mixing_equivalent_decisions():
 
 # -- saturating preemption-fidelity stream -----------------------------------
 
-def test_saturating_stream_preempts_on_both_sim_backends():
+def test_saturating_stream_preempts_on_both_simulator_backends():
     fcfg = fid.FidelityConfig(backends=("py", "vec"), n_requests=32,
                               saturate=True)
     rep = fid.run_fidelity(PROF, fcfg)

@@ -1,5 +1,5 @@
 """End-to-end behaviour tests for the paper's system: real engine +
-router, managed-cluster fault tolerance, elastic scaling, RL plumbing."""
+router, engine failure, router checkpoints, RL plumbing."""
 import dataclasses
 
 import jax
@@ -7,13 +7,14 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import rl_router as rl
-from repro.core.cluster_manager import ManagedCluster, ManagedClusterConfig
 from repro.core.profiles import V100_LLAMA2_7B
 from repro.core.workload import generate, to_requests
 from repro.models import params as params_lib
 from repro.serving.engine import LLMInstance
+from repro.serving.policies import restore_rl_policy
 from repro.serving.request import Request, summarize
 from repro.serving.scheduler import FCFS
+from repro.training.checkpoint import CheckpointManager
 
 PROF = V100_LLAMA2_7B
 
@@ -55,36 +56,18 @@ def test_engine_failure_requeues():
     assert eng.step() == []          # dead engine does nothing
 
 
-def test_managed_cluster_survives_failure_and_scales():
-    cfg = rl.RouterConfig(variant="guided", n_instances=3,
-                          q_arch="decomposed", seed=0)
-    agent = rl.make_agent(cfg)       # untrained: prior-driven routing
-    mgr = ManagedCluster(ManagedClusterConfig(n_instances=3), cfg, PROF,
-                         agent)
-    reqs = to_requests(generate(120, seed=5), rate=20.0, seed=6)
-    stats = mgr.serve(reqs, fault_plan={2.0: "fail:1", 6.0: "add",
-                                        9.0: "restore:1"})
-    assert stats["n"] == 120, "all requests complete despite failure"
-    assert len(stats["events"]) == 3
-    # the elastic instance (id 3) actually served traffic
-    assert any(r.instance == 3 for r in reqs)
-    for r in reqs:
-        assert r.finished is not None
-
-
 def test_router_checkpoint_roundtrip(tmp_path):
+    """A router saved with CheckpointManager comes back through
+    restore_rl_policy, the path ``--policy rl --checkpoint`` serves."""
     cfg = rl.RouterConfig(variant="guided", n_instances=2,
                           q_arch="decomposed", seed=3)
     agent = rl.make_agent(cfg)
-    mgr = ManagedCluster(ManagedClusterConfig(
-        n_instances=2, checkpoint_dir=str(tmp_path)), cfg, PROF, agent)
-    mgr.save_router(step=7)
-    agent2 = rl.make_agent(dataclasses.replace(cfg, seed=99))
-    mgr2 = ManagedCluster(ManagedClusterConfig(
-        n_instances=2, checkpoint_dir=str(tmp_path)), cfg, PROF, agent2)
-    assert mgr2.restore_router()
+    CheckpointManager(str(tmp_path)).save(7, agent.state_dict(),
+                                          sync=True)
+    policy = restore_rl_policy(dataclasses.replace(cfg, seed=99),
+                               str(tmp_path), m=2)
     for a, b in zip(jax.tree.leaves(agent.params),
-                    jax.tree.leaves(agent2.params)):
+                    jax.tree.leaves(policy.agent.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

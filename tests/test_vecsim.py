@@ -3,7 +3,7 @@ Python stepper, fused batched-RL stepping, O(1) outstanding tokens, and
 the gateway's cancellation/autoscaling satellites."""
 import numpy as np
 import pytest
-from _hypothesis_support import given, settings, st
+from _hypothesis_support import example, given, settings, st
 
 from repro.core import batched_rl, rl_router as rl
 from repro.core import state as state_lib
@@ -112,7 +112,7 @@ def test_fail_restore_and_elastic_add_parity():
                 failed = True
             if cluster.t > 1.5 and not added:
                 cluster.add_instance()
-                cluster.instances[0].restore()
+                cluster.instances[0].recover()
                 cluster.instances[0].clock = cluster.t
                 added = True
             alive = cluster.alive()
@@ -128,6 +128,39 @@ def test_fail_restore_and_elastic_add_parity():
     # the added instance served on both backends identically
     assert (any(r.instance == 3 for r in a)
             == any(r.instance == 3 for r in b))
+
+
+@pytest.mark.parametrize("backend", ["vec", "jax"])
+@pytest.mark.parametrize("fail_tick", [26, 47, 59])
+def test_short_outage_parity(backend, fail_tick):
+    """An instance that fails mid-iteration and is recovered a tick
+    later: its clock follows the cluster clock while it is down (the
+    iteration it was in died with it), so recovery resumes it at the
+    cluster time on every backend, clock for clock."""
+    def drive(backend):
+        rs = to_requests(generate(40, seed=3), rate=30.0, seed=4)
+        cluster = Cluster(PROF, 2, backend=backend)
+        pending = sorted(rs, key=lambda r: r.arrival)
+        i, rr, clocks = 0, 0, []
+        while len(cluster.completed) < len(rs) and cluster.t < 500:
+            while i < len(pending) and pending[i].arrival <= cluster.t:
+                cluster.enqueue(pending[i])
+                i += 1
+            if len(clocks) == fail_tick:
+                cluster.fail_instance(0)
+            if len(clocks) == fail_tick + 1:
+                cluster.recover_instance(0)
+            alive = cluster.alive()
+            while cluster.central and alive:
+                cluster.route(alive[rr % len(alive)])
+                rr += 1
+            cluster.advance()
+            clocks.append([inst.clock for inst in cluster.instances])
+        assert len(cluster.completed) == len(rs)
+        return rs, clocks
+    (ra, ca), (rb, cb) = drive("py"), drive(backend)
+    assert ca == cb
+    _assert_request_parity(ra, rb)
 
 
 # -- featurization / scores read the packed arrays ---------------------------
@@ -504,6 +537,7 @@ def test_gateway_rides_vec_backend_with_identical_results():
        pc_tokens=st.sampled_from([0, 256, 4096]),
        inject_failure=st.booleans())
 @settings(max_examples=10, deadline=None)
+@example(seed=0, m=2, pc_tokens=256, inject_failure=True)
 def test_session_cache_parity_property(seed, m, pc_tokens,
                                        inject_failure):
     """Randomized multi-turn session streams through the prefix-cache
@@ -530,7 +564,7 @@ def test_session_cache_parity_property(seed, m, pc_tokens,
                 cluster.fail_instance(0)
                 failed = True
             if failed and cluster.t > 1.5:
-                cluster.instances[0].restore()
+                cluster.instances[0].recover()
                 cluster.instances[0].clock = cluster.t
                 failed = False
             alive = cluster.alive()
