@@ -104,11 +104,13 @@ def _maybe_pallas_prefill(cfg, q, k, v, q_offset):
     return fa_ops.flash_attention(q, k, v, causal=True, q_offset=q_offset)
 
 
-def _maybe_pallas_decode(cfg, q, k, v, kv_len):
+def _maybe_pallas_decode(cfg, q, k, v, kv_len, layer):
+    """k/v the layer-stacked cache [L,B,S,KV,hd]: the kernel reads
+    ``layer`` in place."""
     if not cfg.use_pallas:
         return None
     from repro.kernels.decode_attention import ops as da_ops
-    return da_ops.decode_attention(q, k, v, kv_len=kv_len)
+    return da_ops.decode_attention(q, k, v, kv_len, layer)
 
 
 def _expand_kv_for_tp(cfg: ModelConfig, k: jax.Array, v: jax.Array):
@@ -215,15 +217,19 @@ def self_attention_decode(p: Dict, cfg: ModelConfig, x: jax.Array,
         new["v"], new["v_scale"] = quantize_kv(v_new)
     cache = {name: ops.store_token(buf, layer, position, new[name])
              for name, buf in cache.items()}
-    k_read, v_read = cache["k"][layer], cache["v"][layer]
-    if int8_cache:
-        k_read = dequantize_kv(k_read, cache["k_scale"][layer])
-        v_read = dequantize_kv(v_read, cache["v_scale"][layer])
-    out = _maybe_pallas_decode(cfg, q, k_read, v_read, position + 1)
+    if int8_cache:                  # dequantized: a stack of one layer
+        k_stack = dequantize_kv(cache["k"][layer],
+                                cache["k_scale"][layer])[None]
+        v_stack = dequantize_kv(cache["v"][layer],
+                                cache["v_scale"][layer])[None]
+        at = 0
+    else:
+        k_stack, v_stack, at = cache["k"], cache["v"], layer
+    out = _maybe_pallas_decode(cfg, q, k_stack, v_stack, position + 1, at)
     if out is None:
         kv_pos = jnp.arange(s_max)[None, None, :]          # [1,1,S]
         mask = kv_pos <= position[:, None, None]           # [B,1,S]
-        out = gqa_core(q, k_read, v_read, mask)
+        out = gqa_core(q, k_stack[at], v_stack[at], mask)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
 
 

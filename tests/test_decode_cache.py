@@ -3,6 +3,8 @@ cache in place.  The reference here is the write it replaced: a one-hot
 blend of the new token into its layer's whole cache, written back whole.
 After a prefill, slot inserts and decode steps the cache is bit-identical
 to the reference for every cache kind, and so are the logits."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -149,3 +151,38 @@ def test_store_token_writes_one_entry_per_row():
     want = np.zeros((3, 2, 4, 2), np.float32)
     want[1, 0, 2] = [1, 2]            # row 1's position 4 is past the end
     np.testing.assert_array_equal(out, want)
+
+
+def test_pallas_decode_matches_jnp_decode():
+    """The decode step through the Pallas kernel (interpret mode), which
+    reads each layer's K/V in place out of the stacked cache, serves what
+    the jnp path serves: from one prefilled cache, step after step, the
+    same caches and the same logits to bf16 tolerance."""
+    import dataclasses
+    cfg = REGISTRY["qwen3-0.6b"].reduced(dtype="bfloat16", n_heads=8)
+    assert cfg.n_heads == 2 * cfg.n_kv_heads and cfg.n_layers > 1
+    params = params_lib.init_params(jax.random.PRNGKey(0), cfg)
+    insert = engine._build_fns(cfg, CACHE_LEN)[1]
+    cache = model_lib.init_cache(cfg, SLOTS, CACHE_LEN)
+    for slot, n in PROMPTS:
+        toks = jax.random.randint(jax.random.PRNGKey(slot), (1, n), 0,
+                                  cfg.vocab_size)
+        _, one = model_lib.prefill(params, cfg, tokens=toks,
+                                   cache_len=CACHE_LEN)
+        cache = insert(cache, one, slot)
+    cache["pos"] = cache["pos"].at[FREE].set(CACHE_LEN)
+    served = {}
+    for use_pallas in (False, True):
+        run = dataclasses.replace(cfg, use_pallas=use_pallas)
+        decode = jax.jit(functools.partial(model_lib.decode_step, cfg=run))
+        c, steps = cache, []
+        for t in range(STEPS):
+            toks = jax.random.randint(jax.random.PRNGKey(100 + t),
+                                      (SLOTS,), 0, cfg.vocab_size)
+            lg, c = decode(params, cache=c, tokens=toks)
+            steps.append((np.asarray(lg, np.float32),
+                          jax.tree.map(np.asarray, c)))
+        served[use_pallas] = steps
+    for (lg, c), (ref_lg, ref_c) in zip(served[True], served[False]):
+        np.testing.assert_allclose(lg, ref_lg, rtol=2e-2, atol=2e-2)
+        _assert_equal(c, ref_c)

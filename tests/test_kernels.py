@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps, assert_allclose against the
 pure-jnp oracles (interpret=True executes the kernel body on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,6 +59,69 @@ def test_decode_attention(shape, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+
+
+STACKED = [
+    # (L, S, H, KV, hd, bk): rows get lengths 1, bk, bk + 1 and S
+    (1, 256, 8, 4, 64, 64),            # g 2, one layer
+    (3, 256, 8, 4, 64, 64),            # g 2
+    (3, 512, 16, 2, 64, 128),          # g 8
+    (3, 128, 8, 8, 32, 32),            # KV = H: the TP-expanded layout
+    (3, 256, 4, 1, 128, None),         # MQA, the tile from the shapes
+]
+
+
+def _stacked_case(shape, dtype):
+    n_layers, s, h, kv, hd, bk = shape
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (4, 1, h, hd), dtype)
+    k = jax.random.normal(ks[1], (n_layers, 4, s, kv, hd), dtype)
+    v = jax.random.normal(ks[2], (n_layers, 4, s, kv, hd), dtype)
+    step = bk or s
+    lens = jnp.asarray([1, step, min(step + 1, s), s], jnp.int32)
+    return q, k, v, lens, bk
+
+
+def _assert_matches_ref(out, q, k, v, lens, dtype):
+    ref = da.reference(q, k, v, lens)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("shape", STACKED)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_stacked_in_a_layer_scan(shape, dtype):
+    """Inside a layer scan, as the model calls it, the layer index is a
+    traced value: each layer's output is the oracle's on that layer."""
+    q, k, v, lens, bk = _stacked_case(shape, dtype)
+
+    def body(_, layer):
+        return None, da.decode_attention(q, k, v, lens, layer, block_k=bk,
+                                         interpret=True)
+
+    _, outs = jax.jit(lambda: jax.lax.scan(body, None,
+                                           jnp.arange(k.shape[0])))()
+    for layer in range(k.shape[0]):
+        _assert_matches_ref(outs[layer], q, k[layer], v[layer], lens, dtype)
+
+
+@pytest.mark.parametrize("shape", STACKED)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_stacked_reads_one_layer(shape, dtype):
+    """The other layers are never read: NaNs written into them leave
+    the selected layer's output as the oracle gives it."""
+    q, k, v, lens, bk = _stacked_case(shape, dtype)
+    layer = k.shape[0] // 2
+    others = jnp.arange(k.shape[0]) != layer
+    poison = lambda x: jnp.where(others[:, None, None, None, None],
+                                 jnp.nan, x).astype(dtype)
+    out = jax.jit(functools.partial(da.decode_attention, block_k=bk,
+                                    interpret=True))(
+        q, poison(k), poison(v), lens, jnp.int32(layer))
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    _assert_matches_ref(out, q, k[layer], v[layer], lens, dtype)
 
 
 @pytest.mark.parametrize("shape", [

@@ -19,7 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.decode_attention.kernel import decode_attention_kernel
+from repro.kernels.decode_attention.kernel import (
+    decode_attention_kernel, decode_attention_stacked_kernel)
 from repro.kernels.flash_attention import ops as fa
 from repro.models import model as model_lib
 from repro.models import params as params_lib
@@ -65,6 +66,22 @@ def test_decode_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def test_stacked_decode_kernel_compiles_for_v5e(one_chip):
+    """The kernel on the whole layer-stacked cache at qwen3-0.6b's widths
+    (28 layers, 16 slots x 2048), the layer a traced scalar: its K/V tiles
+    of every KV head fit the chip's fast memory."""
+    n_layers, b, s = QWEN3.n_layers, 16, 2048
+    h, kv, hd = QWEN3.n_heads, QWEN3.n_kv_heads, QWEN3.head_dim
+    bf16 = jnp.bfloat16
+    hlo = _hlo(functools.partial(decode_attention_stacked_kernel,
+                                 interpret=False),
+               one_chip, ((b, 1, h, hd), bf16),
+               ((n_layers, b, s, kv, hd), bf16),
+               ((n_layers, b, s, kv, hd), bf16), ((b,), jnp.int32),
+               ((), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("sq", [37, 128, 200, 512])
 def test_flash_op_compiles_for_v5e(one_chip, sq):
     h, kv, hd = QWEN3.n_heads, QWEN3.n_kv_heads, QWEN3.head_dim
@@ -78,7 +95,9 @@ def test_flash_op_compiles_for_v5e(one_chip, sq):
 def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch):
     """The engine's decode step at published widths (16 slots x 2048, the
     cache donated) stores each token's K/V into the stacked cache in
-    place: no whole-cache temporaries and no copy of the whole cache."""
+    place and the decode-attention kernel reads them there: no copy of
+    the whole cache, and no slice or head-major copy of one layer's K/V
+    (its temporaries are smaller than one layer's K)."""
     # the decode op chooses interpret mode by the default backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(QWEN3, use_pallas=True)
@@ -99,15 +118,26 @@ def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch):
     kv_bytes = sum(a.size * a.dtype.itemsize
                    for a in jax.tree.leaves(cache["layers"]))
     assert kv_bytes > 3.7e9                       # 3.76 GB of bf16 K/V
-    assert mem.temp_size_in_bytes < 0.5e9
+    layer_k = kv_bytes // 2 // QWEN3.n_layers
+    assert layer_k == 67_108_864
+    assert mem.temp_size_in_bytes < layer_k
     assert mem.alias_size_in_bytes >= 3.7e9
     hlo = compiled.as_text()
-    assert "tpu_custom_call" in hlo
+    kernels = re.findall(r"(%\S+) = \S+ custom-call\(.*tpu_custom_call",
+                         hlo)
+    assert kernels and all(n.startswith("%decode_attention.")
+                           for n in kernels), kernels
     stacked = "bf16[%s]" % ",".join(map(str, cache["layers"][0]["k"].shape))
     assert stacked == "bf16[28,16,2048,8,128]"
     copies = re.findall(r"(%\S+) = " + re.escape(stacked)
                         + r"\{[^}]*\} copy\(", hlo)
     assert not copies, copies
+    for layer in ("bf16[16,2048,8,128]", "bf16[1,16,2048,8,128]",
+                  "bf16[16,8,2048,128]"):
+        made = re.findall(r"(%\S+) = " + re.escape(layer)
+                          + r"\{[^}]*\} (?:copy|fusion|dynamic-slice)\(",
+                          hlo)
+        assert not made, (layer, made)
 
 
 DSV2 = get_config("deepseek-v2-lite")
